@@ -72,7 +72,7 @@ def smoke_backend_roundtrip() -> list[str]:
                              jnp.float32) for f in p.fields}
     params = {"dtdx": 0.02, "dtdy": 0.02, "rdx": 1.0, "rdy": 1.0}
     ref = compile_program(p, "jnp")(dict(fields), params)
-    out = compile_program(p, "pallas-tpu", interpret=True)(dict(fields), params)
+    out = compile_program(p, "pallas-tpu")(dict(fields), params)
     err = float(np.abs(np.asarray(ref["qout"]) - np.asarray(out["qout"])).max())
     assert err < 1e-5, f"backend mismatch: {err}"
     return [f"smoke/backend_roundtrip,0,max_err={err:.2e};"

@@ -26,6 +26,7 @@ import time
 import numpy as np
 import jax
 
+from repro.core import enable_compile_cache
 from repro.fv3.dyncore import FV3Config, make_step_ensemble, make_step_sequential
 from repro.fv3.state import ensemble_state, init_state, total_mass
 from repro.train.checkpoint import (latest_step, restore_checkpoint,
@@ -57,7 +58,7 @@ def main():
     ap.add_argument("--npx", type=int, default=24)
     ap.add_argument("--nk", type=int, default=8)
     ap.add_argument("--opt-level", type=int, default=3,
-                    help="automatic optimization ladder (0-3)")
+                    help="automatic optimization ladder (0-4)")
     ap.add_argument("--members", type=int, default=1,
                     help="ensemble members (>1: batched ensemble step)")
     ap.add_argument("--batch", default=None,
@@ -66,6 +67,7 @@ def main():
                          "grid:C | vmap:auto); default: backend's choice")
     ap.add_argument("--ckpt", default="/tmp/fv3_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = FV3Config(npx=args.npx, nk=args.nk, halo=6, n_split=2, k_split=1)
     # donate=True: this driver only ever chains state = step_fn(state), the
@@ -116,8 +118,10 @@ def main():
         state = step_fn(state)
         diagnostics(state, cfg, i + 1, m0)
     dt = time.perf_counter() - t0
+    dev = jax.devices()[0]
     print(f"done: {args.steps} physics steps in {dt:.1f}s "
-          f"({dt / args.steps * 1e3:.0f} ms/step on CPU)")
+          f"({dt / args.steps * 1e3:.0f} ms/step on {dev.platform} "
+          f"{dev.device_kind!r}, compile and checkpoints included)")
     if args.members > 1:
         # chunk-plan report: live state bytes, the per-chunk working set the
         # chunked lowering bounds, and ensemble throughput.  Real

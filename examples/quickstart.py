@@ -67,7 +67,7 @@ def main():
     # pallas-gpu); opt_level selects the automatic pass ladder — the paper's
     # whole optimization pipeline with no per-program hand-tuning
     fn_jnp = compile_program(p, "jnp", opt_level=3)
-    fn_pl = compile_program(p, "pallas-tpu", interpret=True, opt_level=3)
+    fn_pl = compile_program(p, "pallas-tpu", opt_level=3)
     print(f"\nopt_level=3 pipeline:\n{fn_jnp.opt_report.summary()}")
 
     out_jnp = fn_jnp(dict(fields), params)

@@ -21,6 +21,7 @@ from .backend import (  # noqa: F401
     compile_program,
     compile_stencil,
     default_cache,
+    enable_compile_cache,
     donation_supported,
     get_backend,
     parse_batch,

@@ -73,8 +73,8 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
 
     launch_overhead = 1e-6  # per pallas_call / grid step pipeline fill
     if stencil.is_vertical_solver():
-        if vmem_footprint(stencil, sched, (nk, nj, ni), dtype_bytes,
-                          member_chunk=C) > hw.vmem_bytes:
+        if vmem_footprint(stencil, sched, dom, dtype_bytes,
+                          member_chunk=C, hw=hw) > hw.vmem_bytes:
             # whole-column blocks stop fitting at production depths
             # (nk ~ 80 on large tiles) — or the requested member chunk
             # widens them past VMEM; the K-blocked marching schedules
@@ -109,8 +109,8 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
             bj = sched.block_j or nj
             n_blocks *= max(1, ni // bi) * max(1, nj // bj)
         t += launch_overhead * (1 + 0.05 * (n_blocks * m_steps - 1))
-        if vmem_footprint(stencil, sched, (nk, nj, ni), dtype_bytes,
-                          member_chunk=C) > hw.vmem_bytes:
+        if vmem_footprint(stencil, sched, dom, dtype_bytes,
+                          member_chunk=C, hw=hw) > hw.vmem_bytes:
             return float("inf")
     has_regions = any(s.region is not None
                       for c in stencil.computations for s in c.statements)
@@ -189,8 +189,7 @@ def tune_stencil(stencil: Stencil, dom: DomainSpec, *,
                                r["n_evaluated"], from_cache=True)
                     for r in hit]
     results = []
-    for sched in be.feasible_schedules(stencil, (dom.nk, dom.nj, dom.ni),
-                                       hardware=hw):
+    for sched in be.feasible_schedules(stencil, dom, hardware=hw):
         c = model_cost(stencil, sched, dom, hw, n_members=n_members,
                        member_chunk=member_chunk)
         if measure is not None and c != float("inf"):

@@ -30,6 +30,7 @@ from .cache import (  # noqa: F401
     CacheStats,
     TuningCache,
     default_cache,
+    enable_compile_cache,
     make_key,
     set_default_cache,
     stencil_fingerprint,

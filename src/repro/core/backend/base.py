@@ -16,7 +16,7 @@ to offer):
         name = "my-target"
         default_hardware = "tpu-v5e"
         def compile_stencil(self, stencil, dom, *, schedule=None,
-                            hardware=None, interpret=True, dtype=...,
+                            hardware=None, dtype=...,
                             n_members=None, batch="vmap"):
             return <callable fn(fields, params) -> dict>
 
@@ -60,7 +60,7 @@ class Backend(abc.ABC):
     def compile_stencil(self, stencil: Stencil, dom: DomainSpec, *,
                         schedule: Schedule | None = None,
                         hardware: Hardware | str | None = None,
-                        interpret: bool = True, dtype=None,
+                        dtype=None,
                         n_members: int | None = None,
                         batch: str = "vmap") -> Runner:
         """Lower one stencil into ``fn(fields, params) -> dict``.

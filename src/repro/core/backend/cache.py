@@ -9,10 +9,13 @@ object identities, so entries are valid across runs and machines.  Writes
 re-read and merge the on-disk state first, so concurrent processes append
 rather than clobber (last writer wins only on the same key).
 
-The store is a single JSON file (default ``./.repro_cache/tuning.json``,
-overridable via ``$REPRO_CACHE_DIR`` or ``set_default_cache``), written
-atomically.  Hit/miss counters make cache behavior observable in tests and
-benchmarks.
+The store is a single JSON file (default ``.repro_cache/tuning.json`` in
+the checkout, overridable via ``$REPRO_CACHE_DIR`` or
+``set_default_cache``), written atomically.  Hit/miss counters make cache
+behavior observable in tests and benchmarks.
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache at
+a fixed path of the same checkout.
 """
 
 from __future__ import annotations
@@ -30,6 +33,10 @@ from ..stencil.schedule import Schedule
 
 _CACHE_VERSION = 1
 
+#: root of the checkout this package is imported from (``src/repro/core/
+#: backend/`` → root): both caches live there, wherever the process runs
+CHECKOUT = Path(__file__).resolve().parents[4]
+
 #: Version of the analytical cost/schedule model.  Folded into every tuning
 #: key by tune_stencil / tune_cutouts — bump it whenever ``model_cost``,
 #: ``node_bound_seconds``, schedule enumeration or the fusion transforms
@@ -46,8 +53,11 @@ _CACHE_VERSION = 1
 #: feasibility prices C-member blocks, and tuning keys carry the chunk.
 #: v8: rewrite engine — opt_level 4 rewrites (stencil-combine, cross-
 #: computation CSE) reshape stencil bodies before tuning, so fingerprints
-#: of tuned stencils and the footprints the model prices both change.)
-COST_MODEL_VERSION = 8
+#: of tuned stencils and the footprints the model prices both change.
+#: v9: VMEM footprints count the blocks the Pallas lowering allocates —
+#: halo-padded planes rounded to (sublane, lane), double-buffered
+#: pipelined blocks — and J-tiled whole-column schedules.)
+COST_MODEL_VERSION = 9
 
 
 def stencil_fingerprint(stencil: Stencil) -> str:
@@ -103,7 +113,8 @@ class TuningCache:
 
     def __init__(self, path: str | os.PathLike | None = None):
         if path is None:
-            root = os.environ.get("REPRO_CACHE_DIR", ".repro_cache")
+            root = os.environ.get("REPRO_CACHE_DIR",
+                                  CHECKOUT / ".repro_cache")
             path = os.path.join(root, "tuning.json")
         self.path = Path(path)
         if self.path.is_dir():
@@ -190,3 +201,19 @@ def set_default_cache(cache: TuningCache | None) -> None:
     """Swap the process-wide cache (tests point it at a tmp path)."""
     global _default_cache
     _default_cache = cache
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory.  Where ``$JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX already reads it and nothing is set here; otherwise the cache is
+    ``.jax_cache`` in the checkout — a fixed path, since the path is part
+    of the cache's key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

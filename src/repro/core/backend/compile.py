@@ -15,7 +15,7 @@ their last reader — after fusion they never exist in HBM at all, because
 fused subgraphs keep them as kernel-local scratch.
 
 Per-node compiled runners are memoized in-process keyed by
-(stencil fingerprint, schedule, backend, hardware, domain, interpret):
+(stencil fingerprint, schedule, backend, hardware, domain, interpret mode):
 benchmark harnesses and tuning loops compile the same program repeatedly,
 and re-lowering every node each time is pure waste.  Stats are observable
 via :func:`compile_cache_stats`.
@@ -74,10 +74,31 @@ def donation_supported() -> bool:
     return jax.default_backend() in ("gpu", "tpu")
 
 
+def pallas_interpret() -> bool:
+    """True when Pallas kernels run in interpret mode — the one place this
+    is decided, from the platform: the interpreter on the CPU (validation
+    against the jnp oracle), native Mosaic/Triton kernels everywhere else.
+    Lowerings read it when they build a kernel; nothing above them takes
+    an ``interpret`` option."""
+    return jax.default_backend() == "cpu"
+
+
+def varying_zeros(shape, dtype, like=None) -> jax.Array:
+    """Zeros that vary over the same ``shard_map`` manual axes as ``like``.
+
+    Inside ``shard_map`` every value's type carries the mesh axes it varies
+    over; plain zeros vary over none, so a loop carry or scan state seeded
+    with them changes type on its first update and is refused.  Outside
+    ``shard_map`` (or without a template) these are plain zeros."""
+    z = jnp.zeros(shape, dtype)
+    vma = getattr(jax.typeof(like), "vma", None) if like is not None else None
+    return jax.lax.pcast(z, tuple(vma), to="varying") if vma else z
+
+
 def compile_stencil(stencil, dom, *, backend: "str | Backend" = "jnp",
                     schedule: Schedule | None = None,
                     hardware: Hardware | str | None = None,
-                    interpret: bool = True, dtype=None,
+                    dtype=None,
                     memoize: bool = True,
                     n_members: int | None = None,
                     batch: "str | BatchSpec" = "vmap") -> Callable:
@@ -100,20 +121,19 @@ def compile_stencil(stencil, dom, *, backend: "str | Backend" = "jnp",
             stencil, dom, hw=hw, backend=be.name, n_members=n_members))
     if not memoize:
         return be.compile_stencil(stencil, dom, schedule=schedule,
-                                  hardware=hw, interpret=interpret,
-                                  dtype=dtype, n_members=n_members,
-                                  batch=spec)
+                                  hardware=hw, dtype=dtype,
+                                  n_members=n_members, batch=spec)
     key = (stencil_fingerprint(stencil), dom,
            None if schedule is None else dataclasses.astuple(schedule),
-           be.name, hw.name, interpret, None if dtype is None else str(dtype),
+           be.name, hw.name, pallas_interpret(),
+           None if dtype is None else str(dtype),
            n_members, spec.token if n_members else None)
     runner = _runner_memo.get(key)
     if runner is None:
         _runner_stats.misses += 1
         runner = be.compile_stencil(stencil, dom, schedule=schedule,
-                                    hardware=hw, interpret=interpret,
-                                    dtype=dtype, n_members=n_members,
-                                    batch=spec)
+                                    hardware=hw, dtype=dtype,
+                                    n_members=n_members, batch=spec)
         _runner_memo[key] = runner
     else:
         _runner_stats.hits += 1
@@ -162,7 +182,6 @@ def compile_program(program: "StencilProgram",
                     backend: "str | Backend" = "jnp", *,
                     hardware: Hardware | str | None = None,
                     schedule_overrides: Mapping[str, Schedule] | None = None,
-                    interpret: bool = True,
                     donate: bool = False,
                     opt_level: int = 0,
                     n_members: int | None = None,
@@ -284,8 +303,7 @@ def compile_program(program: "StencilProgram",
             dom = program.node_dom(n)
             sched = _resolve_override(n, schedule_overrides)
             r = compile_stencil(n.stencil, dom, backend=be, schedule=sched,
-                                hardware=hw, interpret=interpret,
-                                n_members=stencil_members,
+                                hardware=hw, n_members=stencil_members,
                                 batch=stencil_batch)
             runners.append((n, r))
 
@@ -299,15 +317,14 @@ def compile_program(program: "StencilProgram",
         for name in inputs:
             if name not in env:
                 # consumed before any write and not supplied — the backend
-                # owns allocation, never the user (paper §IV-A).  A varying-
-                # zero from an input keeps shard_map's manual-axes (VMA)
-                # tracking consistent inside scan carries.
+                # owns allocation, never the user (paper §IV-A).  The
+                # barrier keeps XLA from folding the zeros into their
+                # readers, so batched and unbatched programs round alike
+                # (the ensemble's bit-equality contract)
                 decl = fields_decl[name]
-                z = jnp.zeros(lead + dom.padded_shape(decl.interface),
-                              decl.dtype)
-                if template is not None:
-                    z = z + (template.ravel()[0] * 0).astype(decl.dtype)
-                env[name] = z
+                env[name] = jax.lax.optimization_barrier(varying_zeros(
+                    lead + dom.padded_shape(decl.interface), decl.dtype,
+                    template))
         for i, (n, r) in enumerate(runners):
             ins = {f: env[f] for f in n.stencil.fields}
             ps = {p: params[p] for p in n.stencil.params}

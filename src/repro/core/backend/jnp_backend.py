@@ -31,7 +31,7 @@ class JnpBackend(Backend):
     def compile_stencil(self, stencil: Stencil, dom: DomainSpec, *,
                         schedule: Schedule | None = None,
                         hardware: Hardware | str | None = None,
-                        interpret: bool = True, dtype=None,
+                        dtype=None,
                         n_members: int | None = None,
                         batch: "str | BatchSpec" = "vmap") -> Runner:
         fn = compile_jnp(stencil, dom, dtype=dtype or jnp.float32)

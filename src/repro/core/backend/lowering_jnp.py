@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..stencil.domain import DomainSpec
+from .compile import varying_zeros
 from ..stencil.ir import (
     Assign,
     BinOp,
@@ -103,8 +104,9 @@ def _bisect_levels(cwin, target, lo: int, hi: int):
     """
     shape = jnp.broadcast_shapes(jnp.shape(target),
                                  (1,) + tuple(cwin.shape[1:]))
-    lo_a = jnp.full(shape, lo, jnp.int32)
-    hi_a = jnp.full(shape, hi - 1, jnp.int32)
+    # the bisection state varies over whatever shard_map axes cwin does
+    zero = varying_zeros(shape, jnp.int32, cwin)
+    lo_a, hi_a = zero + lo, zero + (hi - 1)
     n = hi - lo
     if n <= 1:
         return lo_a
@@ -307,9 +309,10 @@ def compile_jnp(stencil: Stencil, dom: DomainSpec, *, dtype=jnp.float32):
         env: dict[str, Any] = dict(params)
         for f in stencil.fields:
             env[f] = fields[f]
+        like = fields[stencil.fields[0]] if stencil.fields else None
         for t in temps:
-            env[t] = jnp.zeros(dom.padded_shape(stencil.is_interface(t)),
-                               dtype=dtype)
+            env[t] = varying_zeros(
+                dom.padded_shape(stencil.is_interface(t)), dtype, like)
         for comp in stencil.computations:
             if comp.direction is Direction.PARALLEL:
                 _apply_parallel(comp, env, dom, stencil)

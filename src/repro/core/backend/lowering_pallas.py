@@ -32,8 +32,9 @@ Schedules map onto Pallas as follows (paper §V-A ↔ TPU):
    no leaks between chunks.  Per-invocation VMEM scales by C, which is
    exactly what ``vmem_footprint(member_chunk=C)`` prices for the tuner.
 
-Kernels are validated in ``interpret=True`` mode on CPU against the jnp
-oracle; on real TPUs the same ``pl.pallas_call`` lowers to Mosaic.
+Kernels are validated in interpret mode on CPU against the jnp oracle; on
+real TPUs the same ``pl.pallas_call`` lowers to Mosaic.  The mode comes from
+the platform (:func:`~repro.core.backend.compile.pallas_interpret`).
 """
 
 from __future__ import annotations
@@ -49,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..stencil.domain import DomainSpec
+from . import compile as _compile
 from ..stencil.ir import (
     Assign,
     BinOp,
@@ -70,8 +72,8 @@ from ..stencil.ir import (
     Where,
     expr_contains_level_search,
 )
-from ..stencil.schedule import (Schedule, default_schedule, kblocked_applies,
-                                solver_carried_fields)
+from ..stencil.schedule import (Schedule, default_schedule, j_tile,
+                                kblocked_applies, solver_carried_fields)
 
 _UNARY = {
     "neg": lambda x: -x,
@@ -96,6 +98,37 @@ _BIN = {
 }
 
 
+class _Column:
+    """A field's whole K column over one horizontal window, read a level at
+    a time: ``row(s)`` is level ``s`` (a traced index is fine).  Ref-backed
+    columns index the ref at the level — Mosaic has no dynamic slice of a
+    loaded value; only kernel-local values fall back to one."""
+
+    def __init__(self, shape, row):
+        self.shape = tuple(shape)
+        self.ndim = len(self.shape)
+        self.row = row
+
+    @classmethod
+    def of_ref(cls, ref, jsl, isl):
+        lead = ref.shape[:-3]
+        shape = lead + (ref.shape[-3],) + _window_shape(ref, jsl, isl)
+        if lead:
+            return cls(shape, lambda s: ref[:, s, jsl, isl])
+        return cls(shape, lambda s: ref[s, jsl, isl])
+
+    @classmethod
+    def of_value(cls, col):
+        # K sits at axis -3 so leading member-chunk dims ride through
+        return cls(col.shape, lambda s: jax.lax.dynamic_index_in_dim(
+            col, s, col.ndim - 3, keepdims=False))
+
+
+def _window_shape(ref, jsl: slice, isl: slice):
+    return (len(range(*jsl.indices(ref.shape[-2]))),
+            len(range(*isl.indices(ref.shape[-1]))))
+
+
 def _march_search(e: LevelSearch, read, params, read_col, nk: int):
     """Lower a LevelSearch as an in-kernel *marching loop*: one
     ``fori_loop`` walk over the source layers, accumulating the bracketing
@@ -113,11 +146,6 @@ def _march_search(e: LevelSearch, read, params, read_col, nk: int):
         key = (fl.name, fl.di, fl.dj)
         if key not in cols:
             cols[key] = read_col(fl.name, fl.di, fl.dj)
-
-    def row(col, s):
-        # K sits at axis -3 so leading member-chunk dims ride through
-        return jax.lax.dynamic_index_in_dim(col, s, col.ndim - 3,
-                                            keepdims=False)
 
     if cwin.ndim == 3:
         shape = jnp.broadcast_shapes(jnp.shape(target), tuple(cwin.shape[1:]))
@@ -145,12 +173,12 @@ def _march_search(e: LevelSearch, read, params, read_col, nk: int):
 
     def vals_at(s):
         return {(fl.name, fl.di, fl.dj, fl.dk): jnp.broadcast_to(
-                    lift(row(cols[(fl.name, fl.di, fl.dj)], s + fl.dk)),
+                    lift(cols[(fl.name, fl.di, fl.dj)].row(s + fl.dk)),
                     shape)
                 for fl in finds}
 
     def body(s, acc):
-        take = lift(row(cwin, s)) <= target
+        take = lift(cwin.row(s)) <= target
         fresh = vals_at(s)
         return {k: jnp.where(take, fresh[k], acc[k]) for k in acc}
 
@@ -210,13 +238,18 @@ def _member_index_map(imap, m, *grid):
     return (m,) + tuple(imap(*grid))
 
 
+def _param_spec():
+    """BlockSpec of one scalar parameter: a whole ``(1,)`` array in SMEM,
+    the only memory a Mosaic kernel reads scalars from directly."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _member_specs(specs, chunk: int = 0):
     """Prepend a member block dimension to every array BlockSpec: squeezed
     (``None``, one member per grid step) by default, or a non-squeezed
     extent-``chunk`` dim whose grid axis indexes *chunk blocks* — the
-    hybrid ``vmap:C,grid`` lowering.  Scalar-param specs
-    (``memory_space=ANY``, no block shape) are broadcast across members
-    and pass through untouched."""
+    hybrid ``vmap:C,grid`` lowering.  Scalar-param specs (SMEM, no block
+    shape) are broadcast across members and pass through untouched."""
     out = []
     for spec in specs:
         if spec.block_shape is None:
@@ -274,13 +307,52 @@ def _kshift_read(ref, dk: int, out_nk: int, jsl, isl):
     return _k_align(ref[..., jsl, isl], dk, out_nk)
 
 
-def _region_mask_block(region: Region, dom: DomainSpec):
-    ei, ej = dom.extend
-    ilo, ihi, jlo, jhi = region.resolve(dom.ni, dom.nj)
-    nj_w, ni_w = dom.nj + 2 * ej, dom.ni + 2 * ei
-    jj = jax.lax.broadcasted_iota(jnp.int32, (nj_w, ni_w), 0) - ej
-    ii = jax.lax.broadcasted_iota(jnp.int32, (nj_w, ni_w), 1) - ei
-    return (jj >= jlo) & (jj < jhi) & (ii >= ilo) & (ii < ihi)
+@dataclasses.dataclass(frozen=True)
+class _Tile:
+    """Where a kernel's write window sits in its blocks: the whole padded
+    plane (``bj == 0``), or a tile of ``bj`` J rows (:func:`j_tile` — only
+    for stencils that read no horizontal neighbour) whose rows outside the
+    window are masked off by their absolute row index."""
+
+    dom: DomainSpec
+    bj: int = 0
+
+    def window(self, dj: int = 0, di: int = 0):
+        """Static (j, i) slices of the write window shifted by an offset."""
+        jsl, isl = _hwindow(self.dom, dj, di)
+        return (slice(0, self.bj), isl) if self.bj else (jsl, isl)
+
+    @property
+    def shape2d(self) -> tuple[int, int]:
+        ei, ej = self.dom.extend
+        return (self.bj or self.dom.nj + 2 * ej, self.dom.ni + 2 * ei)
+
+    def rows(self) -> int:
+        """J extent of one block."""
+        return self.bj or self.dom.nj + 2 * self.dom.halo
+
+    def n_tiles(self) -> int:
+        return pl.cdiv(self.dom.nj + 2 * self.dom.halo, self.bj) \
+            if self.bj else 1
+
+    def mask(self, region: Region | None, jt):
+        """Bool ``shape2d`` mask of the cells a statement writes in J tile
+        ``jt`` (a traced grid index), or None when it writes the whole
+        window: the tile's window rows, intersected with ``region``."""
+        if not self.bj and region is None:
+            return None
+        dom = self.dom
+        ei, ej = dom.extend
+        # jj/ii: window coordinates, 0 at the first interior cell
+        j0 = jt * self.bj - dom.halo if self.bj else -ej
+        jj = jax.lax.broadcasted_iota(jnp.int32, self.shape2d, 0) + j0
+        mask = (jj >= -ej) & (jj < dom.nj + ej) if self.bj else None
+        if region is not None:
+            ilo, ihi, jlo, jhi = region.resolve(dom.ni, dom.nj)
+            ii = jax.lax.broadcasted_iota(jnp.int32, self.shape2d, 1) - ei
+            rm = (jj >= jlo) & (jj < jhi) & (ii >= ilo) & (ii < ihi)
+            mask = rm if mask is None else mask & rm
+        return mask
 
 
 def _inline_offset_temps(stencil: Stencil) -> Stencil:
@@ -365,6 +437,8 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
     if stencil.has_level_search():
         bk = nk  # the search marches whole coordinate columns
     whole_k = bk == nk
+    # whole-column stencils with no horizontal reads may tile J instead
+    tile = _Tile(dom, j_tile(stencil, sched) if whole_k else 0)
 
     def kernel(*refs):
         n_in = len(fields) + len(param_names)
@@ -375,10 +449,10 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
         for w in written:
             out_refs[w][...] = in_refs[w][...]
         env: dict[str, Any] = {}
-        # gaxis: the K grid axis shifts right by one when a member grid
-        # axis is prepended (ensemble batching)
-        pid = pl.program_id(gaxis) if not whole_k else 0
-        k0 = pid * bk
+        # gaxis: the K (or J-tile) grid axis shifts right by one when a
+        # member grid axis is prepended (ensemble batching)
+        pid = pl.program_id(gaxis) if not whole_k or tile.bj else 0
+        k0 = 0 if whole_k else pid * bk
 
         def make_read(rows):
             # ``rows`` is the current statement's iteration-row count: its
@@ -388,7 +462,7 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             # are (C, K, J, I) under ``chunk``) batches straight through.
             def read(name, off):
                 di, dj, dk = off
-                jsl, isl = _hwindow(dom, dj, di)
+                jsl, isl = tile.window(dj, di)
                 ref = out_refs.get(name, in_refs.get(name))
                 if name in env and (di, dj) == (0, 0):
                     if dk == 0 and env[name].shape[-3] == rows:
@@ -429,12 +503,11 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                     raise NotImplementedError(
                         f"horizontal-offset search read of in-kernel "
                         f"temporary {name!r}")
-                return env[name]
-            jsl, isl = _hwindow(dom, dj, di)
-            return ref[..., jsl, isl]
+                return _Column.of_value(env[name])
+            jsl, isl = tile.window(dj, di)
+            return _Column.of_ref(ref, jsl, isl)
 
-        ei, ej = dom.extend
-        nj_w, ni_w = dom.nj + 2 * ej, dom.ni + 2 * ei
+        nj_w, ni_w = tile.shape2d
         lead = (chunk,) if chunk else ()
         for st in statements:
             tgt_nk = ksz.get(st.target, nk)
@@ -445,7 +518,7 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             val = _eval_block(st.value, make_read(rows), params,
                               read_col=read_col if whole_k else None, nk=nk)
             klo, khi = st.interval.resolve(tgt_nk)
-            jsl, isl = _hwindow(dom, 0, 0)
+            jsl, isl = tile.window()
             tgt_ref = out_refs.get(st.target)
             if tgt_ref is not None:
                 cur = tgt_ref[..., jsl, isl]
@@ -458,22 +531,30 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             val = jnp.broadcast_to(val, tshape).astype(dt)
             cur = jnp.broadcast_to(cur, tshape).astype(dt)
             mask = (kk >= klo) & (kk < khi)
-            if st.region is not None:
-                mask = mask & _region_mask_block(st.region, dom)[None]
+            pm = tile.mask(st.region, pid)
+            if pm is not None:
+                mask = mask & pm[None]
             new = jnp.where(mask, val, cur)
             if tgt_ref is not None:
                 tgt_ref[..., jsl, isl] = new
             env[st.target] = new
         return
 
-    njp, nip = dom.nj + 2 * dom.halo, dom.ni + 2 * dom.halo
-    grid = (nk // bk,)
+    nip = dom.ni + 2 * dom.halo
+    if tile.bj:
+        grid = (tile.n_tiles(),)
 
-    def block(f_rows):
-        return pl.BlockSpec((f_rows, njp, nip), lambda k: (k, 0, 0))
+        def block(f_rows):
+            return pl.BlockSpec((f_rows, tile.bj, nip), lambda j: (0, j, 0))
+    else:
+        grid = (nk // bk,)
+
+        def block(f_rows):
+            return pl.BlockSpec((f_rows, tile.rows(), nip),
+                                lambda k: (k, 0, 0))
 
     in_specs = ([block(ksz[f] if whole_k else bk) for f in fields] +
-                [pl.BlockSpec(memory_space=pl.ANY) for _ in param_names])
+                [_param_spec() for _ in param_names])
     out_specs = [block(ksz[w] if whole_k else bk) for w in written]
     return kernel, grid, in_specs, out_specs, written, bk
 
@@ -484,11 +565,12 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
 
 
 def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
-                     param_names, chunk: int = 0):
+                     param_names, gaxis: int = 0, chunk: int = 0):
     written = [w for w in stencil.written() if w in stencil.fields]
     fields = list(stencil.fields)
     temps = stencil.temporaries()
     nk = dom.nk
+    tile = _Tile(dom, j_tile(stencil, sched))
     ksz = {f: stencil.k_extent_of(f, nk)
            for f in list(fields) + list(temps)}
 
@@ -513,9 +595,10 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
         for w in written:
             out_refs[w][...] = in_refs[w][...]
 
-        jsl, isl = _hwindow(dom, 0, 0)
-        shape2d = (dom.nj + 2 * dom.extend[1], dom.ni + 2 * dom.extend[0])
+        jsl, isl = tile.window()
+        shape2d = tile.shape2d
         lead = (chunk,) if chunk else ()
+        jt = pl.program_id(gaxis) if tile.bj else 0
 
         def ref_of(name):
             if name in out_refs:
@@ -525,8 +608,8 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             return in_refs[name]
 
         def read_col(name, di, dj):
-            js, is_ = _hwindow(dom, dj, di)
-            return ref_of(name)[..., js, is_]
+            js, is_ = tile.window(dj, di)
+            return _Column.of_ref(ref_of(name), js, is_)
 
         # traced-K level addressing: ellipsis + a traced index is not a
         # Pallas ref indexer, so the leading chunk slice is explicit
@@ -550,7 +633,7 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
 
                     def read_par(name, off, rows=rows):
                         di, dj, dk = off
-                        js, is_ = _hwindow(dom, dj, di)
+                        js, is_ = tile.window(dj, di)
                         return _kshift_read(ref_of(name), dk, rows, js, is_)
                     val = _eval_block(st.value, read_par, params,
                                       read_col=read_col, nk=nk)
@@ -559,8 +642,9 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                     cur = tgt[..., jsl, isl]
                     val = jnp.broadcast_to(val, cur.shape).astype(cur.dtype)
                     mask = (kk >= klo) & (kk < khi)
-                    if st.region is not None:
-                        mask = mask & _region_mask_block(st.region, dom)[None]
+                    pm = tile.mask(st.region, jt)
+                    if pm is not None:
+                        mask = mask & pm[None]
                     tgt[..., jsl, isl] = jnp.where(mask, val, cur)
                 continue
 
@@ -584,7 +668,7 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
 
                 def read_lvl(name, off):
                     di, dj, dk = off
-                    js, is_ = _hwindow(dom, dj, di)
+                    js, is_ = tile.window(dj, di)
                     if (dk == prev and name in carry_names
                             and sched.carry_storage == "vreg"
                             and di == 0 and dj == 0):
@@ -600,9 +684,9 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                     cur = lvl_get(tgt, k, jsl, isl)
                     val = jnp.broadcast_to(val, cur.shape).astype(cur.dtype)
                     active = (k >= sklo) & (k < skhi)
-                    if st.region is not None:
-                        rm = _region_mask_block(st.region, dom)
-                        val = jnp.where(rm, val, cur)
+                    pm = tile.mask(st.region, jt)
+                    if pm is not None:
+                        val = jnp.where(pm, val, cur)
                     newv = jnp.where(active, val, cur)
                     lvl_set(tgt, k, jsl, isl, newv)
                     if st.target in carry_names:
@@ -612,18 +696,19 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             jax.lax.fori_loop(0, hi - lo, body, init_carry())
         return
 
-    njp, nip = dom.nj + 2 * dom.halo, dom.ni + 2 * dom.halo
-    grid = (1,)
+    nip = dom.ni + 2 * dom.halo
+    grid = (tile.n_tiles(),)
 
     def full(f_rows):
-        return pl.BlockSpec((f_rows, njp, nip), lambda _: (0, 0, 0))
+        # one whole column, or one J tile of it per grid step
+        return pl.BlockSpec((f_rows, tile.rows(), nip), lambda j: (0, j, 0))
 
     in_specs = ([full(ksz[f]) for f in fields] +
-                [pl.BlockSpec(memory_space=pl.ANY) for _ in param_names])
+                [_param_spec() for _ in param_names])
     # stencil temporaries live in VMEM scratch — fused subgraphs keep their
     # internalized transients out of HBM entirely (paper §VI-A)
     out_specs = [full(ksz[w]) for w in written]
-    return kernel, grid, in_specs, out_specs, written, temps
+    return kernel, grid, in_specs, out_specs, written, temps, full
 
 
 # ---------------------------------------------------------------------------
@@ -737,7 +822,7 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
                     val = jnp.broadcast_to(val, cur.shape).astype(cur.dtype)
                     active = (k >= sklo) & (k < skhi)
                     if st.region is not None:
-                        rm = _region_mask_block(st.region, dom)
+                        rm = _Tile(dom).mask(st.region, 0)
                         val = jnp.where(rm, val, cur)
                     newv = jnp.where(active, val, cur)
                     lvl_set(tgt, local, jsl, isl, newv)
@@ -766,13 +851,24 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
 
     grid = (n_blocks,)
     in_specs = ([block() for _ in fields] +
-                [pl.BlockSpec(memory_space=pl.ANY) for _ in param_names])
+                [_param_spec() for _ in param_names])
     out_specs = [block() for _ in written]
     return kernel, grid, in_specs, out_specs, written, temps, carried
 
 
+def _pallas_call(kernel, *, vmem_limit: int, **kw):
+    """``pl.pallas_call`` in the platform's mode, with the kernel's
+    scoped-VMEM limit (compiled kernels only; 0 keeps the compiler's
+    default)."""
+    interpret = _compile.pallas_interpret()
+    params = (pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+              if vmem_limit and not interpret else None)
+    return pl.pallas_call(kernel, interpret=interpret,
+                          compiler_params=params, **kw)
+
+
 def _compile_kblocked(stencil: Stencil, dom: DomainSpec, sched: Schedule,
-                      param_names, dtype, interpret: bool,
+                      param_names, dtype, vmem_limit: int,
                       n_members: int | None = None, member_chunk: int = 0):
     kernel, grid, in_specs, out_specs, written, temps, carried = \
         _vertical_kernel_kblocked(stencil, dom, sched, param_names,
@@ -806,10 +902,10 @@ def _compile_kblocked(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                  for p in param_names])
         out_shapes = [jax.ShapeDtypeStruct(shape_of(w), args[0].dtype)
                       for w in written]
-        outs = pl.pallas_call(
+        outs = _pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shapes, scratch_shapes=scratch,
-            interpret=interpret,
+            vmem_limit=vmem_limit,
         )(*args)
         return dict(zip(written, outs))
 
@@ -823,11 +919,15 @@ def _compile_kblocked(stencil: Stencil, dom: DomainSpec, sched: Schedule,
 
 def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
                    schedule: Schedule | None = None, dtype=jnp.float32,
-                   interpret: bool = True, scratch_temps: bool = True,
-                   n_members: int | None = None, member_chunk: int = 0):
+                   scratch_temps: bool = True,
+                   n_members: int | None = None, member_chunk: int = 0,
+                   vmem_limit: int = 0):
     """Compile a stencil into a Pallas-backed functional callable.
 
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
+    Kernels run in interpret mode exactly when
+    :func:`~repro.core.backend.compile.pallas_interpret` says so (the CPU).
+    ``vmem_limit`` is the scoped-VMEM limit each compiled kernel asks the
+    compiler for (0: the compiler's default).
     ``scratch_temps`` keeps vertical-solver temporaries in ``pltpu.VMEM``
     scratch (never materialized in HBM); the GPU backend passes False —
     the TPU memory-space spec does not exist in the Triton lowering — and
@@ -855,7 +955,7 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
                 f"n_members={n_members} (callers pad the member axis)")
         if member_chunk == n_members and n_members == 1:
             member_chunk = 0
-    sched = schedule or default_schedule(stencil, (dom.nk, dom.nj, dom.ni))
+    sched = schedule or default_schedule(stencil, dom)
     param_names = list(stencil.params)
     lead = (n_members,) if n_members else ()
     m_steps = (n_members // member_chunk if member_chunk else n_members)
@@ -871,12 +971,13 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
         # scratch (the GPU backend's parallel thread-block grid cannot
         # order blocks, so it never enumerates this schedule).
         return _compile_kblocked(stencil, dom, sched, param_names, dtype,
-                                 interpret, n_members=n_members,
+                                 vmem_limit, n_members=n_members,
                                  member_chunk=member_chunk)
 
     if stencil.is_vertical_solver():
-        kernel, grid, in_specs, out_specs, written, temps = _vertical_kernel(
-            stencil, dom, sched, param_names, chunk=member_chunk)
+        kernel, grid, in_specs, out_specs, written, temps, block = \
+            _vertical_kernel(stencil, dom, sched, param_names,
+                             gaxis=1 if n_members else 0, chunk=member_chunk)
 
         # scratch refs arrive after the outputs in kernel argument order —
         # the same positions temporaries-as-outputs occupy, so the kernel
@@ -884,13 +985,12 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
         slead = (member_chunk,) if member_chunk else ()
         if scratch_temps:
             scratch = [pltpu.VMEM(
-                slead + dom.padded_shape(stencil.is_interface(t)),
-                dtype) for t in temps]
+                slead + (stencil.k_extent_of(t, dom.nk),)
+                + tuple(block(1).block_shape[1:]), dtype) for t in temps]
         else:
             scratch = []
             out_specs = out_specs + [
-                pl.BlockSpec(dom.padded_shape(stencil.is_interface(t)),
-                             lambda _: (0, 0, 0)) for t in temps]
+                block(stencil.k_extent_of(t, dom.nk)) for t in temps]
         if n_members:
             grid = (m_steps,) + grid
             in_specs = _member_specs(in_specs, chunk=member_chunk)
@@ -906,10 +1006,10 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
             if not scratch_temps:
                 out_shapes += [jax.ShapeDtypeStruct(shape_of(t), dtype)
                                for t in temps]
-            outs = pl.pallas_call(
+            outs = _pallas_call(
                 kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
                 out_shape=out_shapes, scratch_shapes=scratch,
-                interpret=interpret,
+                vmem_limit=vmem_limit,
             )(*args)
             return dict(zip(written, outs[:len(written)]))
 
@@ -946,9 +1046,9 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
                      for p in param_names])
             out_shapes = [jax.ShapeDtypeStruct(shape_of(w), cur[w].dtype)
                           for w in written]
-            outs = pl.pallas_call(
+            outs = _pallas_call(
                 kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-                out_shape=out_shapes, interpret=interpret,
+                out_shape=out_shapes, vmem_limit=vmem_limit,
             )(*args)
             for w, o in zip(written, outs):
                 cur[w] = o

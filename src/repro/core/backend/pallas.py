@@ -2,7 +2,8 @@
 
 Both lower through the same ``pl.pallas_call`` kernel generator
 (``lowering_pallas``); on a real accelerator the call lowers to Mosaic (TPU)
-or Triton (GPU), while ``interpret=True`` executes on CPU for validation.
+or Triton (GPU), while on the CPU the kernels run in interpret mode for
+validation (:func:`~repro.core.backend.compile.pallas_interpret`).
 What distinguishes the two backends is the *schedule policy*: each resolves
 feasibility, defaults and heuristics against its own hardware descriptor
 (TPU lane/sublane/VMEM rules vs GPU warp/shared-memory rules), so the same
@@ -35,20 +36,19 @@ class PallasTPUBackend(Backend):
     def compile_stencil(self, stencil: Stencil, dom: DomainSpec, *,
                         schedule: Schedule | None = None,
                         hardware: Hardware | str | None = None,
-                        interpret: bool = True, dtype=None,
+                        dtype=None,
                         n_members: int | None = None,
                         batch: "str | BatchSpec" = "grid") -> Runner:
+        hw = self.resolve_hw(hardware)
         if schedule is None:
-            schedule = self.default_schedule(
-                stencil, (dom.nk, dom.nj, dom.ni), hardware)
+            schedule = self.default_schedule(stencil, dom, hw)
         kwargs = {} if dtype is None else {"dtype": dtype}
 
         def lower(members=None, chunk=0):
             return compile_pallas(stencil, dom, schedule=schedule,
-                                  interpret=interpret,
                                   scratch_temps=self.scratch_temps,
                                   n_members=members, member_chunk=chunk,
-                                  **kwargs)
+                                  vmem_limit=hw.vmem_limit_bytes, **kwargs)
 
         if not n_members:
             return lower()

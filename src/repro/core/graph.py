@@ -245,7 +245,7 @@ class StencilProgram:
 
     # -- execution ---------------------------------------------------------------
     def compile(self, backend: str = "jnp", *, hardware=None,
-                schedule_overrides=None, interpret: bool = True,
+                schedule_overrides=None,
                 donate: bool = False, opt_level: int = 0,
                 n_members: int | None = None,
                 batch: str = "vmap",
@@ -267,7 +267,7 @@ class StencilProgram:
 
         return compile_program(self, backend, hardware=hardware,
                                schedule_overrides=schedule_overrides,
-                               interpret=interpret, donate=donate,
+                               donate=donate,
                                opt_level=opt_level, n_members=n_members,
                                batch=batch, verify=verify)
 

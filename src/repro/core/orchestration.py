@@ -54,7 +54,7 @@ def bind_constants(fn: Callable, **consts) -> Callable:
 
 
 def orchestrate(program_or_fn, *, backend: str = "jnp", hardware=None,
-                donate: bool = True, interpret: bool = True,
+                donate: bool = True,
                 opt_level: int = 0) -> Callable:
     """Compile a StencilProgram (or plain function) into one jitted step.
 
@@ -69,7 +69,7 @@ def orchestrate(program_or_fn, *, backend: str = "jnp", hardware=None,
 
     if isinstance(program_or_fn, StencilProgram):
         fn = compile_program(program_or_fn, backend, hardware=hardware,
-                             interpret=interpret, opt_level=opt_level)
+                             opt_level=opt_level)
     else:
         fn = program_or_fn
     if donate and donation_supported():

@@ -50,8 +50,8 @@ def _fused_schedule(program: StencilProgram, node: Node, hw: Hardware):
     """The schedule the fused node will actually lower with: its own if one
     survived fusion, else the hardware heuristic (which acceptance assigns,
     so the footprint check below and the emitted kernel always agree)."""
-    shape = program.node_dom(node).shape()
-    return node.schedule or heuristic_schedule(node.stencil, shape, hw=hw)
+    dom = program.node_dom(node)
+    return node.schedule or heuristic_schedule(node.stencil, dom, hw=hw)
 
 
 def _fused_fits(program: StencilProgram, node: Node, hw: Hardware) -> bool:
@@ -61,9 +61,9 @@ def _fused_fits(program: StencilProgram, node: Node, hw: Hardware) -> bool:
     the schedule it will lower with fits fast memory."""
     if (max(node.extend) + node.stencil.max_halo() > program.dom.halo):
         return False
-    shape = program.node_dom(node).shape()
     sched = _fused_schedule(program, node, hw)
-    return vmem_footprint(node.stencil, sched, shape) <= hw.vmem_bytes
+    return vmem_footprint(node.stencil, sched, program.node_dom(node),
+                          hw=hw) <= hw.vmem_bytes
 
 
 def _greedy_otf(program: StencilProgram, state: State, hw: Hardware) -> int:

@@ -215,9 +215,9 @@ class CrossComputationCSE(RewriteRule):
         node = match.nodes[0]
         rewritten = self._rewrite(node.stencil, match)
         hw = ctx.hw()
-        shape = program.node_dom(node).shape()
-        sched = node.schedule or heuristic_schedule(rewritten, shape, hw=hw)
-        return vmem_footprint(rewritten, sched, shape) <= hw.vmem_bytes
+        dom = program.node_dom(node)
+        sched = node.schedule or heuristic_schedule(rewritten, dom, hw=hw)
+        return vmem_footprint(rewritten, sched, dom, hw=hw) <= hw.vmem_bytes
 
     def _rewrite(self, st: Stencil, match: Match) -> Stencil:
         e, idxs, (def_ci, def_si) = match.payload

@@ -143,49 +143,106 @@ def kblocked_applies(stencil: Stencil, sched: Schedule, nk: int, *,
             and nk % sched.block_k == 0 and solver_k_blockable(stencil))
 
 
+def j_tileable(stencil: Stencil) -> bool:
+    """True when whole-column blocks may be split into tiles of J rows: the
+    stencil reads no horizontal neighbour (a row tile carries no halo rows)
+    and K cannot be tiled instead — K offsets, interface fields, level
+    searches, or a solver the K-blocked march cannot take."""
+    if stencil.max_halo():
+        return False
+    if stencil.is_vertical_solver():
+        return not solver_k_blockable(stencil)
+    return (stencil.has_k_offsets() or stencil.has_interface_fields()
+            or stencil.has_level_search())
+
+
+def j_tile(stencil: Stencil, sched: Schedule) -> int:
+    """Rows per J tile the Pallas lowering uses under ``sched`` (0: the
+    block holds the whole padded plane) — shared by the lowering and
+    :func:`vmem_footprint`, like :func:`kblocked_applies`."""
+    return sched.block_j if (sched.block_j and j_tileable(stencil)) else 0
+
+
+def _dims(dom_shape) -> tuple[int, int, int, int]:
+    """(nk, nj, ni, halo) of a DomainSpec, or of an interior (nk, nj, ni)
+    shape taken as halo-free."""
+    if hasattr(dom_shape, "halo"):
+        return dom_shape.nk, dom_shape.nj, dom_shape.ni, dom_shape.halo
+    nk, nj, ni = dom_shape
+    return nk, nj, ni, 0
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def vmem_footprint(stencil: Stencil, sched: Schedule, dom_shape,
-                   dtype_bytes: int = 4, member_chunk: int = 0) -> int:
-    """Bytes of fast on-chip memory one kernel invocation touches under this
-    schedule (VMEM block on TPU; shared-memory tile on GPU).  The byte
-    count itself is hardware-independent; callers compare it against
-    ``hw.vmem_bytes``.  K-interface buffers carry one extra level
-    (they only ever appear in whole-K blocks — interface and center fields
-    never co-tile in K).  K-blocked vertical solvers hold ``block_k`` rows
-    per field plus one carry plane per loop-carried field.
+                   dtype_bytes: int = 4, member_chunk: int = 0,
+                   hw: Hardware | str | None = None) -> int:
+    """Bytes of fast on-chip memory one kernel invocation holds under this
+    schedule (VMEM on TPU; shared-memory tile on GPU); callers compare it
+    against ``hw.vmem_bytes``.  ``dom_shape`` is a
+    :class:`~repro.core.stencil.domain.DomainSpec` (or an interior
+    ``(nk, nj, ni)``, taken as halo-free).
+
+    On TPU this counts the blocks the Pallas lowering allocates: each block
+    spans the halo-padded plane (or a J tile of it, :func:`j_tile`), its
+    second-minor and minor extents rounded up to ``(hw.sublane, hw.lane)``;
+    every input and output block is double-buffered by the pipeline, and
+    temporaries and carry planes are held once.  K-interface buffers carry
+    one extra level (they only ever appear in whole-K blocks).  K-blocked
+    vertical solvers hold ``block_k`` rows per field.  The model is an
+    upper bound: Mosaic may pack an unaligned plane tighter than the
+    rounded one.
 
     ``member_chunk=C`` prices a chunk-batched invocation
     (``batch="vmap:C,grid"``): every block and carry buffer gains a leading
     C-member extent, so the footprint scales by C — the feasibility limit
     on how wide the inner batch of the hybrid chunk loop can go."""
-    nk, nj, ni = dom_shape
+    hw = resolve_hardware(hw)
+    nk, nj, ni, halo = _dims(dom_shape)
     mult = max(1, member_chunk)
-    bi = sched.block_i or ni
-    bj = sched.block_j or nj
     vertical = stencil.is_vertical_solver()
     if vertical:
         whole_k = not kblocked_applies(stencil, sched, nk)
         bk = nk if whole_k else sched.block_k
     else:
         whole_k = (not sched.k_as_grid or stencil.has_interface_fields()
-                   or stencil.has_level_search())
+                   or stencil.has_level_search() or stencil.has_k_offsets())
         bk = nk if whole_k else (sched.block_k or nk)
-    total = 0
-    for name in tuple(stencil.fields) + tuple(stencil.temporaries()):
-        k_size = bk + 1 if (whole_k and stencil.is_interface(name)) else bk
-        total += mult * bi * bj * k_size * dtype_bytes
-    if vertical and not whole_k:
-        total += (mult * len(solver_carried_fields(stencil))
-                  * bi * bj * dtype_bytes)
-    return total
+
+    def rows(name):
+        return bk + 1 if (whole_k and stencil.is_interface(name)) else bk
+
+    carried = (len(solver_carried_fields(stencil))
+               if vertical and not whole_k else 0)
+    temps = stencil.temporaries()
+    if hw.kind == "gpu":
+        # a thread-block tile of the interior, held once
+        plane = mult * (sched.block_i or ni) * (sched.block_j or nj) \
+            * dtype_bytes
+        return (sum(rows(f) for f in tuple(stencil.fields) + tuple(temps))
+                + carried) * plane
+    plane = (mult * _round_up(j_tile(stencil, sched) or nj + 2 * halo,
+                              hw.sublane)
+             * _round_up(ni + 2 * halo, hw.lane) * dtype_bytes)
+    written = [w for w in stencil.written() if w in stencil.fields]
+    pipelined = sum(rows(f) for f in tuple(stencil.fields) + tuple(written))
+    return (2 * pipelined + sum(rows(t) for t in temps) + carried) * plane
+
+
+def _k_slabs(nk: int) -> list[int]:
+    """K-slab heights a horizontal kernel's grid can walk: divisors of nk."""
+    return [b for b in (16, 8, 4, 1) if b < nk and nk % b == 0]
 
 
 def _feasible_tpu(stencil: Stencil, dom_shape, dtype_bytes: int,
                   hw: Hardware) -> Iterator[Schedule]:
-    nk, nj, ni = dom_shape
+    nk, nj, ni, halo = _dims(dom_shape)
     vertical = stencil.is_vertical_solver()
     has_regions = any(s.region is not None
                       for c in stencil.computations for s in c.statements)
-    lane, sublane = hw.lane, hw.sublane
+    sublane = hw.sublane
     # interface fields (nk+1 levels) never co-tile with centers in K: any
     # K slab of mixed extents would misalign block boundaries, so interface
     # stencils only get whole-column blocks (same rule as K offsets below);
@@ -199,32 +256,56 @@ def _feasible_tpu(stencil: Stencil, dom_shape, dtype_bytes: int,
         if solver_k_blockable(stencil):
             k_opts += [b for b in (4, 8, 16, 32)
                        if b < nk and nk % b == 0]
-        # the vertical lowering holds the full horizontal window per block
-        # (halo reads need it) — never offer IJ tiles the kernel generator
-        # would silently ignore
-        i_opts, j_opts = [0], [0]
     else:
         k_opts = ([0] if (stencil.has_interface_fields()
                           or stencil.has_level_search())
-                  else [1, 4, 8, 16, 0])
-        i_opts = [0] if ni <= 2 * lane else [0, lane, 2 * lane]
-        j_opts = [0, sublane, 4 * sublane, 16 * sublane]
+                  else _k_slabs(nk) + [0])
+    # the lowering keeps the whole lane extent in every block; J tiles
+    # exist only where whole columns are required and no halo row is read
+    j_opts = [0]
+    if j_tileable(stencil):
+        j_opts += [b for b in (sublane, 4 * sublane, 16 * sublane)
+                   if b < nj + 2 * halo]
     region_opts = ["predicated", "split"] if has_regions else ["predicated"]
     carry_opts = ["vreg", "vmem"] if vertical else ["vreg"]
-    for bi, bj, bk, reg, carry in itertools.product(
-            i_opts, j_opts, bk_dedup(k_opts, nk), region_opts, carry_opts):
+    for bj, bk, reg, carry in itertools.product(
+            j_opts, bk_dedup(k_opts, nk), region_opts, carry_opts):
         if vertical and bk != 0 and carry != "vreg":
             continue  # K-blocked marching always carries in registers
-        s = Schedule(block_i=bi, block_j=bj, block_k=bk,
+        s = Schedule(block_i=0, block_j=bj, block_k=bk,
                      k_as_grid=not vertical, carry_storage=carry,
                      region_strategy=reg)
-        if vmem_footprint(stencil, s, dom_shape, dtype_bytes) > hw.vmem_bytes:
+        if vmem_footprint(stencil, s, dom_shape, dtype_bytes,
+                          hw=hw) > hw.vmem_bytes:
             continue
         # stencils with k offsets need whole-K blocks (no overlapping blocks
         # across the K grid on TPU)
         if not vertical and stencil.has_k_offsets() and bk != 0:
             continue
         yield s
+
+
+def _fitting_tpu(stencil: Stencil, base: Schedule, dom_shape,
+                 dtype_bytes: int, hw: Hardware, budget: int) -> Schedule:
+    """``base`` if its blocks fit ``budget``; else the largest K block or J
+    tile the stencil admits that does (the smallest one if none fits)."""
+    nk = _dims(dom_shape)[0]
+    cands = [base]
+    if stencil.is_vertical_solver() and solver_k_blockable(stencil):
+        cands += [dataclasses.replace(base, block_k=b, carry_storage="vreg")
+                  for b in (32, 16, 8, 4) if b < nk and nk % b == 0]
+    if j_tileable(stencil):
+        cands += [dataclasses.replace(base, block_j=b * hw.sublane)
+                  for b in (16, 4, 1)]
+    if not stencil.is_vertical_solver() and not (
+            stencil.has_k_offsets() or stencil.has_interface_fields()
+            or stencil.has_level_search()):
+        cands += [dataclasses.replace(base, block_k=b) for b in _k_slabs(nk)]
+    for s in cands:
+        if vmem_footprint(stencil, s, dom_shape, dtype_bytes,
+                          hw=hw) <= budget:
+            return s
+    return cands[-1]
 
 
 def _feasible_gpu(stencil: Stencil, dom_shape, dtype_bytes: int,
@@ -234,7 +315,7 @@ def _feasible_gpu(stencil: Stencil, dom_shape, dtype_bytes: int,
     blocks are allowed only when they fit (they essentially never do), so
     the enumeration is dominated by small IJ tiles — the paper's DaCe/GPU
     maps — with K either a grid dimension or an in-kernel loop."""
-    nk, nj, ni = dom_shape
+    nk, nj, ni, _ = _dims(dom_shape)
     vertical = stencil.is_vertical_solver()
     has_regions = any(s.region is not None
                       for c in stencil.computations for s in c.statements)
@@ -260,7 +341,8 @@ def _feasible_gpu(stencil: Stencil, dom_shape, dtype_bytes: int,
         s = Schedule(block_i=bi, block_j=bj, block_k=bk,
                      k_as_grid=not vertical, carry_storage=carry,
                      region_strategy=reg)
-        if vmem_footprint(stencil, s, dom_shape, dtype_bytes) > hw.vmem_bytes:
+        if vmem_footprint(stencil, s, dom_shape, dtype_bytes,
+                          hw=hw) > hw.vmem_bytes:
             continue
         yield s
 
@@ -292,49 +374,56 @@ def default_schedule(stencil: Stencil, dom_shape, dtype_bytes: int = 4,
     """The backend's default before any tuning (paper's 'Default' row in
     Table III): untransformed storage choices (memory-backed carries,
     predicated regions) on the largest tile the hardware's feasibility
-    rules allow — whole-domain blocks on TPU, a warp-aligned tile that
-    fits shared memory on GPU (whole-domain blocks are never GPU-feasible,
-    so defaulting to them would contradict ``feasible_schedules``)."""
+    rules allow — whole-domain blocks on TPU where they fit VMEM, else the
+    largest K block or J tile that does; a warp-aligned tile that fits
+    shared memory on GPU (whole-domain blocks are never GPU-feasible, so
+    defaulting to them would contradict ``feasible_schedules``)."""
     hw = resolve_hardware(hw)
     vertical = stencil.is_vertical_solver()
     whole_k = (vertical or stencil.has_interface_fields()
                or stencil.has_level_search())
     if hw.kind == "gpu":
-        nk, nj, ni = dom_shape
+        nk, nj, ni, _ = _dims(dom_shape)
         bi = min(ni, 4 * hw.lane)
         bj = 8
         while (vmem_footprint(stencil,
                               Schedule(block_i=bi, block_j=bj,
                                        block_k=0 if whole_k else 1,
                                        k_as_grid=not vertical),
-                              dom_shape, dtype_bytes) > hw.vmem_bytes
+                              dom_shape, dtype_bytes, hw=hw) > hw.vmem_bytes
                and bj > 1):
             bj //= 2
         return Schedule(block_i=bi, block_j=bj,
                         block_k=0 if whole_k else 1,
                         k_as_grid=not vertical,
                         carry_storage="vmem", region_strategy="predicated")
-    return Schedule(block_i=0, block_j=0, block_k=0,
+    base = Schedule(block_i=0, block_j=0, block_k=0,
                     k_as_grid=not vertical,
                     carry_storage="vmem", region_strategy="predicated")
+    return _fitting_tpu(stencil, base, dom_shape, dtype_bytes, hw,
+                        hw.vmem_bytes)
 
 
 def heuristic_schedule(stencil: Stencil, dom_shape, dtype_bytes: int = 4,
                        hw: Hardware | str | None = None) -> Schedule:
     """Initial heuristics (paper §VI-A), per hardware kind.
 
-    TPU: smallest VMEM-fitting K slab for horizontal stencils (maximizes
-    grid parallelism while keeping full IJ for halo reuse); full-column
-    blocks with VREG carries for vertical solvers.
+    TPU: the tallest K slab whose blocks fill at most half of VMEM for
+    horizontal stencils (full IJ for halo reuse); full-column blocks with
+    VREG carries for vertical solvers, or the largest K block / J tile
+    that fits where whole columns do not.
 
     GPU: a warp-aligned IJ thread-block tile with a one-level K slab —
     occupancy over reuse, the classic CUDA stencil starting point.
     """
     hw = resolve_hardware(hw)
-    nk, nj, ni = dom_shape
+    nk, nj, ni, _ = _dims(dom_shape)
     if stencil.is_vertical_solver():
-        return Schedule(block_i=0, block_j=0, block_k=0, k_as_grid=False,
-                        carry_storage="vreg", region_strategy="predicated")
+        return _fitting_tpu(
+            stencil, Schedule(block_i=0, block_j=0, block_k=0,
+                              k_as_grid=False, carry_storage="vreg",
+                              region_strategy="predicated"),
+            dom_shape, dtype_bytes, hw, hw.vmem_bytes)
     # whole-column blocks only for K-offset / interface / level-search
     # stencils (interface and center fields never co-tile in K; searches
     # read whole coordinate columns) — decided BEFORE the GPU branch so the
@@ -348,17 +437,15 @@ def heuristic_schedule(stencil: Stencil, dom_shape, dtype_bytes: int = 4,
         bj = 4
         while (vmem_footprint(stencil, Schedule(block_i=bi, block_j=bj,
                                                 block_k=bk), dom_shape,
-                              dtype_bytes) > hw.vmem_bytes and bj > 1):
+                              dtype_bytes, hw=hw) > hw.vmem_bytes
+               and bj > 1):
             bj //= 2
         return Schedule(block_i=bi, block_j=bj, block_k=bk, k_as_grid=True,
                         carry_storage="vreg", region_strategy="predicated")
-    if whole_k:
-        return Schedule(block_i=0, block_j=0, block_k=0, k_as_grid=True,
-                        carry_storage="vreg", region_strategy="predicated")
-    bk = 1
-    while (vmem_footprint(stencil, Schedule(block_k=bk), dom_shape,
-                          dtype_bytes) <= hw.vmem_bytes // 2 and bk < nk):
-        bk *= 2
-    bk = min(bk, nk)
-    return Schedule(block_i=0, block_j=0, block_k=bk, k_as_grid=True,
+    base = Schedule(block_i=0, block_j=0, block_k=0, k_as_grid=True,
                     carry_storage="vreg", region_strategy="predicated")
+    if whole_k:
+        return _fitting_tpu(stencil, base, dom_shape, dtype_bytes, hw,
+                            hw.vmem_bytes)
+    return _fitting_tpu(stencil, dataclasses.replace(base, block_k=nk),
+                        dom_shape, dtype_bytes, hw, hw.vmem_bytes // 2)

@@ -277,7 +277,7 @@ def make_vertical_remap(cfg: FV3Config, dom: DomainSpec,
     -> (delp_ref, remapped_dict)`` plus the compiled runner (for
     introspection) as ``remap.run``."""
     prog = build_remap_program(cfg, dom, fields)
-    run = compile_program(prog, backend, hardware=hardware, interpret=True,
+    run = compile_program(prog, backend, hardware=hardware,
                           opt_level=opt_level)
 
     def remap(delp, field_dict, params):
@@ -355,7 +355,7 @@ def _make_programs(cfg: FV3Config, dom: DomainSpec, backend: str,
     :func:`repro.core.analysis.resolve_verify_mode`)."""
     progs = _build_programs(cfg, dom)
     runners = tuple(
-        compile_program(p, backend, hardware=hardware, interpret=True,
+        compile_program(p, backend, hardware=hardware,
                         opt_level=opt_level, n_members=n_members,
                         batch=batch, verify=verify)
         for p in progs)
@@ -556,6 +556,9 @@ def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
         return jitted(state)
 
     step.counters = counters
+    # ahead-of-time: step.lower(state).compile() — compile time apart from
+    # run time, and the lowered text shows which kernels the step holds
+    step.lower = jitted.lower
     step.opt_report = {p.name: r.opt_report for p, r in zip(progs, runners)}
     step.n_kernels = sum(r.n_kernels for r in runners)
     step.programs = progs
@@ -765,7 +768,7 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     # the remap program is purely vertical (no horizontal reads), so it
     # never participates in halo/compute overlap — compile it plain
     run_remap = compile_program(progs[3], backend, hardware=hardware,
-                                interpret=True, opt_level=lvl, **memb)
+                                opt_level=lvl, **memb)
     ov = None
     if overlap and ml == 1:
         cands = tuple(
@@ -802,7 +805,7 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
         runners = tuple(c.full_run for c in ov) + (run_remap,)
     else:
         runners = tuple(
-            compile_program(p, backend, hardware=hardware, interpret=True,
+            compile_program(p, backend, hardware=hardware,
                             opt_level=lvl, **memb)
             for p in progs[:3]) + (run_remap,)
 
@@ -835,9 +838,7 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     spec = (P(member_axis, "tile", "y", "x") if member_axis
             else P("tile", "y", "x"))
     fields = all_state_fields(cfg)
-    from repro.jaxcompat import shard_map
-
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(dict.fromkeys(fields, spec),),
         out_specs=dict.fromkeys(fields, spec),

@@ -91,7 +91,6 @@ def written_fields(program: StencilProgram) -> tuple[str, ...]:
 
 def make_overlapped_runner(program: StencilProgram, *,
                            backend: str = "jnp", hardware=None,
-                           interpret: bool = True,
                            opt_level: int = 0,
                            verify: str | None = None) -> Callable | None:
     """Compile ``program`` into ``fn(stale, fresh, params) -> outputs``.
@@ -108,7 +107,7 @@ def make_overlapped_runner(program: StencilProgram, *,
         return None
 
     full_run = compile_program(program, backend, hardware=hardware,
-                               interpret=interpret, opt_level=opt_level,
+                               opt_level=opt_level,
                                verify=verify)
     outputs = written_fields(program)
 
@@ -146,7 +145,7 @@ def make_overlapped_runner(program: StencilProgram, *,
     for tag, sdom, (oi, oj), slab, src, dst in specs:
         sp = _strip_program(program, sdom, oi, oj, tag)
         run = compile_program(sp, backend, hardware=hardware,
-                              interpret=interpret, opt_level=strip_level,
+                              opt_level=strip_level,
                               verify=verify)
         strips.append((run, slab, src, dst))
 

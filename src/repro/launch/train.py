@@ -21,7 +21,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, smoke_config
-from repro.jaxcompat import make_mesh
 from repro.data.pipeline import DataConfig, DataIterator
 from repro.models import transformer as T
 from repro.parallel.sharding import dp_axes, init_params, param_shardings
@@ -37,7 +36,8 @@ def build_mesh(model_parallel: int):
     if n == 1:
         return None  # single-device smoke path
     data, model = plan_mesh(n, model_parallel=min(model_parallel, n))
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main():

@@ -81,7 +81,7 @@ def test_feasible_schedules_tpu_vs_gpu_rules():
     # whole-domain blocks TPU VMEM accommodates are infeasible on 48 KiB
     assert all(s.block_i % 32 == 0 and s.block_i > 0 for s in gpu)
     assert all(
-        vmem_footprint(_lap, s, shape) <= P100.vmem_bytes
+        vmem_footprint(_lap, s, shape, hw=P100) <= P100.vmem_bytes
         for s in gpu)
     assert not any(s.block_i == 0 for s in gpu)
     assert {s.to_dict()["block_i"] for s in gpu} != \
@@ -94,7 +94,7 @@ def test_backend_heuristic_schedules_differ_by_hardware():
     gpu_sched = get_backend("pallas-gpu").heuristic_schedule(_lap, shape)
     assert tpu_sched.block_i == 0          # full IJ for halo reuse in VMEM
     assert gpu_sched.block_i % 32 == 0 and gpu_sched.block_i > 0
-    assert vmem_footprint(_lap, gpu_sched, shape) <= P100.vmem_bytes
+    assert vmem_footprint(_lap, gpu_sched, shape, hw=P100) <= P100.vmem_bytes
 
 
 @gtstencil
@@ -139,7 +139,7 @@ def test_compile_program_backends_match_jnp(backend):
     fields = {f: jnp.asarray(rng.uniform(0.5, 1.5, dom.padded_shape()),
                              jnp.float32) for f in ("q", "out")}
     ref = compile_program(p, "jnp")(dict(fields))
-    got = compile_program(p, backend, interpret=True)(dict(fields))
+    got = compile_program(p, backend)(dict(fields))
     np.testing.assert_allclose(np.asarray(ref["out"]), np.asarray(got["out"]),
                                rtol=1e-5, atol=1e-5)
 
@@ -151,7 +151,7 @@ def test_compile_program_schedule_overrides():
                              jnp.float32) for f in ("q", "out")}
     ref = compile_program(p, "jnp")(dict(fields))
     got = compile_program(
-        p, "pallas-tpu", interpret=True,
+        p, "pallas-tpu",
         schedule_overrides={"_lap": Schedule(block_k=2)})(dict(fields))
     np.testing.assert_allclose(np.asarray(ref["out"]), np.asarray(got["out"]),
                                rtol=1e-5, atol=1e-5)
@@ -170,7 +170,7 @@ def test_fv3_acoustic_step_roundtrips_jnp_vs_pallas():
                              jnp.float32)
               for f in ("u", "v", "delp", "pt", "w", "cosa", "sina")}
     ref = compile_program(p, "jnp")(dict(fields), params)
-    got = compile_program(p, "pallas-tpu", interpret=True)(dict(fields), params)
+    got = compile_program(p, "pallas-tpu")(dict(fields), params)
     for k in ("w", "delpc", "ptc"):
         np.testing.assert_allclose(np.asarray(ref[k]), np.asarray(got[k]),
                                    rtol=2e-5, atol=2e-5, err_msg=k)

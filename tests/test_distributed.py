@@ -28,12 +28,12 @@ def test_halo_distributed_matches_reference():
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.jaxcompat import make_mesh, shard_map
 from repro.fv3.topology import Decomposition
 from repro.fv3.halo import exchange_reference, make_halo_exchanger
 N, h, nk = 8, 3, 2
 dec = Decomposition(layout=(2, 2), n_local=N // 2, halo=h)
-mesh = make_mesh((6, 2, 2), ("tile", "y", "x"))
+mesh = jax.make_mesh((6, 2, 2), ("tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 ex = make_halo_exchanger(dec)
 rng = np.random.default_rng(0)
 glob = rng.standard_normal((6, nk, N + 2 * h, N + 2 * h)).astype(np.float32)
@@ -49,7 +49,7 @@ def run(b):
     def inner(lb):
         lb = lb.reshape(nk, nl + 2 * h, nl + 2 * h)
         return ex({"q": lb})["q"].reshape(1, 1, 1, nk, nl+2*h, nl+2*h)
-    return shard_map(inner, mesh=mesh, in_specs=P("tile", "y", "x"),
+    return jax.shard_map(inner, mesh=mesh, in_specs=P("tile", "y", "x"),
                      out_specs=P("tile", "y", "x"))(b)
 res = np.asarray(jax.jit(run)(jnp.asarray(blocks)))
 refg = np.asarray(exchange_reference({"q": jnp.asarray(glob)}, h)["q"])
@@ -69,14 +69,14 @@ print("HALO_OK", err)
 def test_dycore_distributed_matches_sequential():
     out = run_sub("""
 import numpy as np, jax
-from repro.jaxcompat import make_mesh
 from repro.fv3.dyncore import FV3Config, make_step_sequential, make_step_distributed
 from repro.fv3.state import init_state, blocks_from_global, global_from_blocks
 cfg = FV3Config(npx=12, nk=2, halo=6, layout=(2, 2), n_split=1, k_split=1,
                 n_tracers=1)
 state = init_state(cfg)
 s_seq = make_step_sequential(cfg)(state)
-mesh = make_mesh((6, 2, 2), ("tile", "y", "x"))
+mesh = jax.make_mesh((6, 2, 2), ("tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 blocks = blocks_from_global(state, cfg)
 b = make_step_distributed(cfg, mesh)(blocks)
 s_dist = global_from_blocks({k: np.asarray(v) for k, v in b.items()}, cfg)
@@ -97,13 +97,13 @@ def test_dycore_distributed_opt4_drops_delpc_exchange_bitwise():
     bit-identical to the opt_level=3 step, with the step reporting the
     rewrite applied."""
     out = run_sub("""
-import numpy as np
-from repro.jaxcompat import make_mesh
+import numpy as np, jax
 from repro.fv3.dyncore import FV3Config, make_step_distributed
 from repro.fv3.state import init_state, blocks_from_global
 cfg = FV3Config(npx=12, nk=2, halo=6, layout=(2, 2), n_split=2, k_split=1,
                 n_tracers=1)
-mesh = make_mesh((6, 2, 2), ("tile", "y", "x"))
+mesh = jax.make_mesh((6, 2, 2), ("tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 blocks = blocks_from_global(init_state(cfg), cfg)
 step3 = make_step_distributed(cfg, mesh, overlap=False, opt_level=3)
 step4 = make_step_distributed(cfg, mesh, overlap=False, opt_level=4)
@@ -125,12 +125,12 @@ def test_halo_exchanger_carries_leading_member_dim():
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.jaxcompat import make_mesh, shard_map
 from repro.fv3.topology import Decomposition
 from repro.fv3.halo import make_halo_exchanger
 N, h, nk, M = 8, 3, 2, 3
 dec = Decomposition(layout=(2, 2), n_local=N // 2, halo=h)
-mesh = make_mesh((6, 2, 2), ("tile", "y", "x"))
+mesh = jax.make_mesh((6, 2, 2), ("tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 ex = make_halo_exchanger(dec)
 nl = dec.n_local
 rng = np.random.default_rng(0)
@@ -139,13 +139,13 @@ def run_batched(b):
     def inner(lb):
         lb = lb.reshape(M, nk, nl+2*h, nl+2*h)
         return ex({"q": lb})["q"].reshape(1, 1, 1, M, nk, nl+2*h, nl+2*h)
-    return shard_map(inner, mesh=mesh, in_specs=P(None, "tile", "y", "x"),
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(None, "tile", "y", "x"),
                      out_specs=P("tile", "y", "x", None))(b)
 def run_single(b):
     def inner(lb):
         lb = lb.reshape(nk, nl+2*h, nl+2*h)
         return ex({"q": lb})["q"].reshape(1, 1, 1, nk, nl+2*h, nl+2*h)
-    return shard_map(inner, mesh=mesh, in_specs=P("tile", "y", "x"),
+    return jax.shard_map(inner, mesh=mesh, in_specs=P("tile", "y", "x"),
                      out_specs=P("tile", "y", "x"))(b)
 res_b = np.moveaxis(np.asarray(jax.jit(run_batched)(jnp.asarray(blocks))), 3, 0)
 res_s = np.stack([np.asarray(jax.jit(run_single)(jnp.asarray(blocks[m])))
@@ -164,14 +164,14 @@ def test_member_sharded_matches_unsharded():
     step on that member's initial state."""
     out = run_sub("""
 import numpy as np, jax
-from repro.jaxcompat import make_mesh
 from repro.fv3.dyncore import FV3Config, make_step_sequential, make_step_distributed
 from repro.fv3.state import ensemble_state, blocks_from_global, global_from_blocks
 cfg = FV3Config(npx=12, nk=2, halo=6, layout=(1, 1), n_split=1, k_split=1,
                 n_tracers=1)
 M = 2
 ens0 = ensemble_state(cfg, M)
-mesh = make_mesh((M, 6, 1, 1), ("member", "tile", "y", "x"))
+mesh = jax.make_mesh((M, 6, 1, 1), ("member", "tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 4)
 blocks = {}
 for m in range(M):
     bm = blocks_from_global({k: v[m] for k, v in ens0.items()}, cfg)
@@ -200,7 +200,6 @@ def test_lm_sharded_loss_matches_single_device():
     code = """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.jaxcompat import make_mesh
 from repro.configs import smoke_config
 from repro.models import transformer as T
 from repro.parallel.sharding import init_params, param_shardings
@@ -210,7 +209,8 @@ params = init_params(defs, jax.random.PRNGKey(0))
 tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab)
 labels = jax.random.randint(jax.random.PRNGKey(2), (4, 64), 0, cfg.vocab)
 l_single = float(T.loss_fn(params, tokens, labels, cfg, dtype=jnp.float32))
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shards = param_shardings(defs, mesh)
 p_sh = jax.device_put(params, shards)
 t_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))

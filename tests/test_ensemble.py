@@ -432,14 +432,14 @@ def test_chunked_member_sharded_matches_unsharded():
     root = Path(__file__).resolve().parents[1]
     code = """
 import numpy as np, jax
-from repro.jaxcompat import make_mesh
 from repro.fv3.dyncore import FV3Config, make_step_sequential, make_step_distributed
 from repro.fv3.state import ensemble_state, blocks_from_global, global_from_blocks
 cfg = FV3Config(npx=12, nk=2, halo=6, layout=(1, 1), n_split=1, k_split=1,
                 n_tracers=1)
 M, D = 4, 2
 ens0 = ensemble_state(cfg, M)
-mesh = make_mesh((D, 6, 1, 1), ("member", "tile", "y", "x"))
+mesh = jax.make_mesh((D, 6, 1, 1), ("member", "tile", "y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 4)
 blocks = {}
 for m in range(M):
     bm = blocks_from_global({k: v[m] for k, v in ens0.items()}, cfg)
@@ -537,12 +537,12 @@ def test_model_cost_prices_member_chunk():
     # member_chunk=0 is exactly the pre-chunk model
     assert model_cost(st, sched, dom, n_members=M, member_chunk=0) == c_grid
     # footprint scales linearly with C ...
-    f1 = vmem_footprint(st, sched, (dom.nk, dom.nj, dom.ni))
-    f4 = vmem_footprint(st, sched, (dom.nk, dom.nj, dom.ni), member_chunk=4)
+    hw = get_hardware("p100")  # 48 KiB shared memory
+    f1 = vmem_footprint(st, sched, dom, hw=hw)
+    f4 = vmem_footprint(st, sched, dom, member_chunk=4, hw=hw)
     assert f4 == 4 * f1
     # ... and a chunk wider than VMEM is infeasible (M large enough that
     # the chunk is genuine — the model clamps C to M like chunk_for does)
-    hw = get_hardware("p100")  # 48 KiB shared memory
     too_wide = 2 * (hw.vmem_bytes // f1 + 1)
     assert model_cost(st, sched, dom, hw, n_members=2 * too_wide,
                       member_chunk=too_wide) == float("inf")

@@ -121,8 +121,7 @@ def test_search_interp_matches_jnp_interp_nk80(backend):
     nk = 80
     dom = DomainSpec(ni=3, nj=3, nk=nk, halo=2)
     pe, fm, pe_ref = _interp_inputs(nk, dom)
-    run = compile_stencil(S.interface_interp, dom, backend=backend,
-                          interpret=True)
+    run = compile_stencil(S.interface_interp, dom, backend=backend)
     fi = np.asarray(run({"fm": jnp.asarray(fm), "pe": jnp.asarray(pe),
                          "pe_ref": jnp.asarray(pe_ref),
                          "fi": jnp.zeros(dom.padded_shape(interface=True),
@@ -152,8 +151,7 @@ def test_search_interp_jnp_pallas_bit_equal():
            "fi": jnp.zeros(dom.padded_shape(interface=True), jnp.float32)}
     outs = {}
     for backend in ("jnp", "pallas-tpu"):
-        run = compile_stencil(S.interface_interp, dom, backend=backend,
-                              interpret=True)
+        run = compile_stencil(S.interface_interp, dom, backend=backend)
         outs[backend] = np.asarray(run(dict(ins), {})["fi"])
     h = dom.halo
     I = np.s_[:, h:h + dom.nj, h:h + dom.ni]
@@ -199,7 +197,7 @@ def test_remap_nk80_compiles_and_matches_oracle(backend, opt_level):
     params = default_params(cfg)
     p = build_remap_program(cfg, dom, fields=("pt",))
     ref = compile_program(p, "jnp")(dict(ins), params)
-    got = compile_program(p, backend, interpret=True,
+    got = compile_program(p, backend,
                           opt_level=opt_level)(dict(ins), params)
     h, N = cfg.halo, cfg.npx
     I = np.s_[:, h:h + N, h:h + N]
@@ -256,8 +254,8 @@ def test_cross_computation_prev_read_not_blockable():
     ref = compile_stencil(_cross_comp_prev_read, dom, backend="jnp")(
         dict(ins), {})
     got = compile_stencil(_cross_comp_prev_read, dom, backend="pallas-tpu",
-                          schedule=Schedule(block_k=4, k_as_grid=False),
-                          interpret=True)(dict(ins), {})
+                          schedule=Schedule(block_k=4, k_as_grid=False))(
+                              dict(ins), {})
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(got[k]),
                                       err_msg=k)
@@ -290,16 +288,17 @@ def test_solver_k_blockable_rules():
 def test_kblocked_schedules_enumerated_and_fit_vmem():
     """At production depth on a large tile, whole-column blocks exceed VMEM
     and the K-blocked marching schedules are the only feasible options."""
+    # 4 MiB holds the double-buffered halo-padded blocks of K blocks of 4
+    # levels (~2.5 MiB), not those of 8 levels or of whole columns
     tiny = Hardware("test-tiny-vmem", peak_flops=1e12, hbm_bw=1e11,
-                    link_bw=0, vmem_bytes=2 * 1024 * 1024, kind="tpu")
-    dom_shape = (80, 96, 128)
-    scheds = list(feasible_schedules(S.precompute_pe, dom_shape, hw=tiny))
+                    link_bw=0, vmem_bytes=4 * 1024 * 1024, kind="tpu")
+    dom = DomainSpec(ni=128, nj=96, nk=80, halo=3)
+    scheds = list(feasible_schedules(S.precompute_pe, dom, hw=tiny))
     assert scheds, "nk=80 must stay schedulable via K blocking"
     assert all(s.block_k != 0 for s in scheds), \
         "whole-column blocks cannot fit this VMEM"
     assert all(not s.k_as_grid for s in scheds)
     # the cost model agrees: whole-column is priced infeasible, blocked not
-    dom = DomainSpec(ni=128, nj=96, nk=80, halo=3)
     whole = Schedule(block_k=0, k_as_grid=False)
     assert model_cost(S.precompute_pe, whole, dom, tiny) == float("inf")
     assert model_cost(S.precompute_pe, scheds[0], dom, tiny) < float("inf")
@@ -321,8 +320,8 @@ def test_kblocked_kernel_matches_whole_column(stencil, fields, bk):
                           jnp.float32) for f in fields}
     ref = compile_stencil(stencil, dom, backend="jnp")(dict(ins), {})
     sched = Schedule(block_i=0, block_j=0, block_k=bk, k_as_grid=False)
-    got = compile_stencil(stencil, dom, backend="pallas-tpu", schedule=sched,
-                          interpret=True)(dict(ins), {})
+    got = compile_stencil(stencil, dom, backend="pallas-tpu",
+                          schedule=sched)(dict(ins), {})
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(got[k]),
                                       err_msg=k)
@@ -350,7 +349,7 @@ def test_kblocked_fused_solver_legality_and_correctness():
     ref = compile_stencil(fused.stencil, dom, backend="jnp")(dict(ins), params)
     sched = Schedule(block_k=4, k_as_grid=False)
     got = compile_stencil(fused.stencil, dom, backend="pallas-tpu",
-                          schedule=sched, interpret=True)(dict(ins), params)
+                          schedule=sched)(dict(ins), params)
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(got[k]),
                                       err_msg=k)

@@ -128,7 +128,7 @@ def test_fv3_acoustic_roundtrip_opt0_vs_opt3_both_backends():
     I = np.s_[:, h:h + N, h:h + N]
     ref = compile_program(p, "jnp")(dict(fields), params)
     for backend in ("jnp", "pallas-tpu"):
-        got = compile_program(p, backend, interpret=True,
+        got = compile_program(p, backend,
                               opt_level=3)(dict(fields), params)
         for k in ("w", "delpc", "ptc"):
             np.testing.assert_allclose(
@@ -217,7 +217,7 @@ def test_fused_chain_jnp_vs_pallas_bitwise(spec):
 
     base = np.asarray(compile_program(p, "jnp")(dict(fields))[out])[sl]
     j3 = compile_program(p, "jnp", opt_level=3)
-    p3 = compile_program(p, "pallas-tpu", interpret=True, opt_level=3)
+    p3 = compile_program(p, "pallas-tpu", opt_level=3)
     got_j = np.asarray(j3(dict(fields))[out])[sl]
     got_p = np.asarray(p3(dict(fields))[out])[sl]
     assert p3.n_kernels <= j3.n_kernels <= len(offsets)

@@ -127,8 +127,8 @@ def _csw_setup():
 @pytest.mark.parametrize("backend", ["jnp", "pallas-tpu"])
 def test_opt4_applies_pattern_rewrites_and_matches_opt3_bitwise(backend):
     _, p, fields, params = _csw_setup()
-    f3 = compile_program(p, backend, interpret=True, opt_level=3)
-    f4 = compile_program(p, backend, interpret=True, opt_level=4)
+    f3 = compile_program(p, backend, opt_level=3)
+    f4 = compile_program(p, backend, opt_level=4)
     # the acceptance criterion: both pattern rewrites fire on c_sw+riem
     assert f4.opt_report.rules.get("cross_cse", 0) >= 1
     assert f4.opt_report.rules.get("stencil_combine", 0) >= 1
@@ -146,7 +146,7 @@ def test_value_preserving_segment_levels_2_to_4(backend):
     # levels 2-4 are bit-identical; level 0 stays allclose (strength
     # reduction at level >= 1 re-associates)
     _, p, fields, params = _csw_setup()
-    outs = {lvl: compile_program(p, backend, interpret=True,
+    outs = {lvl: compile_program(p, backend,
                                  opt_level=lvl)(dict(fields), params)
             for lvl in (0, 2, 3, 4)}
     for k in outs[2]:

@@ -139,7 +139,7 @@ def test_forward_integral(rng):
 def test_pallas_matches_jnp(rng, stencil, fields, params):
     fs = {f: randf(rng, 0.5, 2.5) for f in fields}
     o1 = compile_jnp(stencil, DOM)(fs, params)
-    o2 = compile_pallas(stencil, DOM, interpret=True)(fs, params)
+    o2 = compile_pallas(stencil, DOM)(fs, params)
     for k in o1:
         np.testing.assert_allclose(np.asarray(o1[k]), np.asarray(o2[k]),
                                    rtol=1e-5, atol=1e-5)
@@ -153,7 +153,7 @@ def test_pallas_matches_jnp(rng, stencil, fields, params):
 def test_pallas_schedules_equivalent(rng, sched):
     fs = {f: randf(rng) for f in ("q", "u", "flux")}
     o1 = compile_jnp(flux_region, DOM)(fs)
-    o2 = compile_pallas(flux_region, DOM, schedule=sched, interpret=True)(fs)
+    o2 = compile_pallas(flux_region, DOM, schedule=sched)(fs)
     np.testing.assert_allclose(np.asarray(o1["flux"]),
                                np.asarray(o2["flux"]), rtol=1e-5)
 
@@ -161,8 +161,8 @@ def test_pallas_schedules_equivalent(rng, sched):
 def test_vertical_carry_storage_equivalent(rng):
     fs = {f: randf(rng, 0.5, 2.5) for f in ("a", "b", "c", "d", "x")}
     o1 = compile_pallas(thomas, DOM, schedule=Schedule(
-        carry_storage="vreg", k_as_grid=False), interpret=True)(fs)
+        carry_storage="vreg", k_as_grid=False))(fs)
     o2 = compile_pallas(thomas, DOM, schedule=Schedule(
-        carry_storage="vmem", k_as_grid=False), interpret=True)(fs)
+        carry_storage="vmem", k_as_grid=False))(fs)
     np.testing.assert_allclose(np.asarray(o1["x"]), np.asarray(o2["x"]),
                                rtol=1e-6)

@@ -317,7 +317,7 @@ def test_dycore_clean_under_full_verification(backend, opt_level):
     cfg = FV3Config(npx=8, nk=4, halo=6)
     dom = cfg.seq_dom()
     for p in _build_programs(cfg, dom):
-        fn = compile_program(p, backend, interpret=True,
+        fn = compile_program(p, backend,
                              opt_level=opt_level, verify="full")
         assert fn.verify_mode == "full"
 

@@ -75,7 +75,7 @@ def test_interface_build_and_diff_roundtrip(backend):
     rng = np.random.default_rng(0)
     delp = jnp.asarray(rng.uniform(0.5, 1.5, dom.padded_shape()), jnp.float32)
     pe0 = jnp.zeros(dom.padded_shape(interface=True), jnp.float32)
-    f = compile_stencil(_iface_build, dom, backend=backend, interpret=True)
+    f = compile_stencil(_iface_build, dom, backend=backend)
     pe = f({"delp": delp, "pe": pe0}, {"ptop": 10.0})["pe"]
     assert pe.shape == dom.padded_shape(interface=True)
     h = dom.halo
@@ -83,7 +83,7 @@ def test_interface_build_and_diff_roundtrip(backend):
     ref = 10.0 + np.concatenate(
         [np.zeros((1,) + delp.shape[1:]), np.cumsum(np.asarray(delp), 0)], 0)
     np.testing.assert_allclose(np.asarray(pe)[I], ref[I], rtol=1e-6)
-    g = compile_stencil(_iface_diff, dom, backend=backend, interpret=True)
+    g = compile_stencil(_iface_diff, dom, backend=backend)
     dp = g({"pe": pe, "dp": jnp.zeros(dom.padded_shape(), jnp.float32)}, {})["dp"]
     np.testing.assert_allclose(np.asarray(dp)[I], np.asarray(delp)[I],
                                rtol=1e-5, atol=1e-6)
@@ -215,7 +215,7 @@ def test_remap_program_pallas_matches_jnp(backend):
                              jnp.float32)}
     params = default_params(cfg)
     ref = compile_program(p, "jnp")(dict(ins), params)
-    got = compile_program(p, backend, interpret=True)(dict(ins), params)
+    got = compile_program(p, backend)(dict(ins), params)
     h, N = cfg.halo, cfg.npx
     I = np.s_[:, h:h + N, h:h + N]
     for k in ("delp_out", "pt_out"):
