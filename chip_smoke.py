@@ -1,9 +1,9 @@
-"""Smoke run of the FV3-lite dycore on one TPU chip, at C64 x 80 levels.
+"""Smoke run of the FV3-lite dycore on one TPU chip, at C128 x 80 levels.
 
 Drives the main path through its public entry points — ``init_state``,
 ``make_step_sequential`` / ``make_step_ensemble`` → ``compile_program`` —
-on a whole cubed sphere of six 64 x 64 tiles with 80 levels, halo 6 and
-4 tracers (24,576 columns on one chip), and checks what comes out:
+on a whole cubed sphere of six 128 x 128 tiles with 80 levels, halo 6 and
+4 tracers (98,304 columns on one chip), and checks what comes out:
 
 * phase A: two physics steps with the jnp backend at opt level 0 (the
   oracle), the jnp backend at opt level 3, and the Pallas TPU backend at opt
@@ -11,12 +11,8 @@ on a whole cubed sphere of six 64 x 64 tiles with 80 levels, halo 6 and
 * phase B: one step of a 2-member ensemble on the Pallas backend's member
   grid, each member compared with the sequential Pallas step on it.
 
-C64 is a cut from the production C128: at C128 every Pallas kernel and
-program compiles and runs alone on a v5e, but the Pallas step does not
-finish (its acoustic substep stalls once d_sw runs after c_sw and the halo
-exchange).  ``--npx 128`` runs the full size.
-
-Each phase prints its compile seconds, a smoke step time (median over the
+Each phase prints its trace-and-lower and compile seconds, a smoke step
+time (median over the
 steps run, under ``block_until_ready`` — not a benchmark), the process's
 peak device bytes, that every field is finite, the mass drift, and the
 largest interior error against its reference with the tolerance and why.
@@ -57,7 +53,8 @@ TIMED_EXTRA = 2
 #: relative to the reference field's largest interior magnitude, and why.
 #: This dycore grows a perturbation several times over per physics step
 #: (on a v5e at C64, opt 3 and opt 0 differ by 2.5e-4 after one step and
-#: 7.1e-4 after two); a wrong stencil, halo or kernel errs by order 1e-1.
+#: 7.1e-4 after two; 8.2e-4 after two at C128); a wrong stencil, halo or
+#: kernel errs by order 1e-1.
 CHECKS = {
     ("jnp opt3", "jnp opt0"): (
         5e-3, "opt 3 strength-reduces x**2.0 and x**0.5 to x*x and sqrt, "
@@ -122,12 +119,14 @@ def assert_native(lowered, backend: str) -> int:
 def run_steps(step, state, n_compared: int, n_timed_extra: int):
     """Compile ``step`` ahead of time, run ``n_compared`` steps (their
     states kept), then ``n_timed_extra`` more for timing only.  Returns the
-    kept states, the lowered and the compiled step, compile seconds and
+    kept states, the lowered and the compiled step, (trace-and-lower,
+    compile) seconds — a compile-cache hit shortens only the second — and
     step times."""
     t0 = time.perf_counter()
     lowered = step.lower(state)
+    t1 = time.perf_counter()
     compiled = lowered.compile()
-    compile_s = time.perf_counter() - t0
+    compile_s = (t1 - t0, time.perf_counter() - t1)
     times, kept, s = [], [], state
     for i in range(n_compared + n_timed_extra):
         t = time.perf_counter()
@@ -139,7 +138,7 @@ def run_steps(step, state, n_compared: int, n_timed_extra: int):
 
 
 def report(label: str, name: str, cfg, outs: list, refs: dict,
-           mass0: float, compile_s: float, times, n_mosaic: int):
+           mass0: float, compile_s: tuple, times, n_mosaic: int):
     """Print one phase's line and raise on any failed check.  ``outs`` are
     the phase's states after each compared step; ``refs`` maps a CHECKS
     reference name to its states after the same steps."""
@@ -148,7 +147,8 @@ def report(label: str, name: str, cfg, outs: list, refs: dict,
     out = outs[-1]
     finite = all(bool(jnp.isfinite(v).all()) for v in out.values())
     drift = total_mass(out, cfg) / mass0 - 1.0
-    print(f"[{label}] {name}: compile_s={compile_s:.1f} "
+    print(f"[{label}] {name}: lower_s={compile_s[0]:.1f} "
+          f"compile_s={compile_s[1]:.1f} "
           f"smoke_step_ms={1e3 * statistics.median(times):.1f} "
           f"(median of {len(times)}, smoke run, not a benchmark) "
           f"peak_bytes_in_use={_peak_bytes()} finite={finite} "
@@ -212,9 +212,8 @@ def phase_b(cfg, seq_compiled, n_members: int = 2):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--npx", type=int, default=64,
-                    help="cells per tile edge (default 64; 128 is the "
-                         "production C128, whose Pallas step stalls)")
+    ap.add_argument("--npx", type=int, default=128,
+                    help="cells per tile edge (default 128: C128)")
     args = ap.parse_args()
     dev = _device_line()
     print(f"jax {jax.__version__}  device_kind={dev['kind']!r}  "

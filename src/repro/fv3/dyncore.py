@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import StencilProgram, compile_program, donation_supported
 from repro.core.backend import register_cache_clear
 from repro.core.backend.batching import BatchSpec, parse_batch, scan_chunked
+from repro.core.hardware import detect_hardware
 from repro.core.stencil import DomainSpec
 from . import stencils as S
 from .halo import exchange_reference, make_halo_exchanger
@@ -518,8 +519,18 @@ def _remap_iteration(cfg, runners, params, halo_fn, state, metrics,
     return st
 
 
+def _kernel_step_options(backend: str) -> dict | None:
+    """XLA compiler options for a step holding the attached chip's Pallas
+    kernels (``Hardware.kernel_step_options``); None off a TPU and for
+    the jnp backend."""
+    hw = detect_hardware()
+    if hw is None or not str(backend).startswith("pallas"):
+        return None
+    return dict(hw.kernel_step_options) or None
+
+
 def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
-                   metrics, params, counters, *, unroll: bool,
+                   metrics, params, counters, *, backend: str, unroll: bool,
                    donate: bool,
                    member_chunks: tuple[int, int] | None = None) -> Callable:
     """Shared tail of the sequential/ensemble step factories: the
@@ -547,8 +558,9 @@ def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
     else:
         _step = _inner
 
-    jitted = (jax.jit(_step, donate_argnums=(0,))
-              if donate and donation_supported() else jax.jit(_step))
+    jitted = jax.jit(
+        _step, donate_argnums=(0,) if donate and donation_supported() else (),
+        compiler_options=_kernel_step_options(backend))
 
     @functools.wraps(_step)
     def step(state: dict) -> dict:
@@ -605,7 +617,7 @@ def make_step_sequential(cfg: FV3Config, *, backend: str = "jnp",
     metrics = _metric_terms(cfg, (6,) + dom.padded_shape())
     return _assemble_step(cfg, progs, runners, runners_v,
                           _reference_halo_fn(cfg), metrics, params, counters,
-                          unroll=unroll, donate=donate)
+                          backend=backend, unroll=unroll, donate=donate)
 
 
 def make_step_ensemble(cfg: FV3Config, n_members: int, *,
@@ -670,7 +682,7 @@ def make_step_ensemble(cfg: FV3Config, n_members: int, *,
                for k, v in base_metrics.items()}
     step = _assemble_step(cfg, progs, runners, runners_v,
                           _reference_halo_fn(cfg), metrics, params, counters,
-                          unroll=unroll, donate=donate,
+                          backend=backend, unroll=unroll, donate=donate,
                           member_chunks=member_chunks)
     step.n_members = n_members
     step.batch = spec.token
