@@ -49,27 +49,27 @@ ROOT = Path(__file__).resolve().parent
 A_STEPS = 2
 #: extra steps run only to time a phase (its compared states are kept)
 TIMED_EXTRA = 2
-#: (phase, reference) -> largest interior error after the compared steps,
-#: relative to the reference field's largest interior magnitude, and why.
-#: This dycore grows a perturbation several times over per physics step
-#: (on a v5e at C64, opt 3 and opt 0 differ by 2.5e-4 after one step and
-#: 7.1e-4 after two; 8.2e-4 after two at C128); a wrong stencil, halo or
-#: kernel errs by order 1e-1.
+#: (phase, reference) -> limit on the largest per-field interior error
+#: (RMS of the difference over the reference field's standard deviation)
+#: after the compared steps, and why.  This dycore grows a rounding
+#: difference several times over per physics step, so the limit sits
+#: between sound readings and planted faults (a 1% error in the flux
+#: coefficient dtdx, a dropped pt halo exchange), measured at C12 on the
+#: CPU: sound 1.2e-4 after 2 steps, faults 4.1e-3 and 8.6e-2.
 CHECKS = {
     ("jnp opt3", "jnp opt0"): (
-        5e-3, "opt 3 strength-reduces x**2.0 and x**0.5 to x*x and sqrt, "
-              "which round differently from the TPU's pow; grown over 2 "
-              "steps (7.1e-4 at C64 and 8.2e-4 at C128 on a TPU v5e)"),
+        1e-3, "opt 3 strength-reduces x**2.0 and x**0.5 to x*x and sqrt, "
+              "which round differently from pow; grown over 2 steps"),
     ("pallas-tpu opt3", "jnp opt0"): (
-        5e-3, "as for jnp opt3 against jnp opt0"),
+        1e-3, "as for jnp opt3 against jnp opt0"),
     ("pallas-tpu opt3", "jnp opt3"): (
-        1e-3, "the same rewritten program; Mosaic and XLA may round "
-              "division and sqrt an ulp apart, grown over 2 steps (bitwise "
-              "equal at C64 on a TPU v5e)"),
+        1e-5, "the same rewritten program; Mosaic and XLA may round "
+              "division and sqrt an ulp apart (bitwise equal at C64 on a "
+              "TPU v5e)"),
     ("ensemble", "pallas-tpu opt3"): (
         1e-5, "the member grid runs the sequential step's kernels "
-              "(bitwise equal on the CPU); XLA may fuse the batched glue "
-              "code differently"),
+              "(bitwise equal at C64 on a TPU v5e); XLA may fuse the "
+              "batched glue code differently"),
 }
 
 
@@ -89,14 +89,17 @@ def _interior(cfg, v):
     return np.asarray(v)[..., h:h + n, h:h + n]
 
 
-def _max_rel_error(cfg, got: dict, ref: dict) -> tuple[float, str]:
-    """Largest interior |got - ref| over all fields, each relative to the
-    reference field's largest interior magnitude; and the field."""
+def _rel_error(cfg, got: dict, ref: dict) -> tuple[float, str]:
+    """Largest per-field interior error over all fields: the RMS of
+    ``got - ref`` over the field's standard deviation in ``ref``, so a
+    field's constant offset (pt and delp sit near 1) hides nothing, and
+    rounding differences that a limiter flips at a few points weigh less
+    than an error spread over the sphere; and the field."""
     worst, worst_f = 0.0, ""
     for f, r in ref.items():
         r = _interior(cfg, r)
-        scale = float(np.abs(r).max()) or 1.0
-        err = float(np.abs(_interior(cfg, got[f]) - r).max()) / scale
+        d = _interior(cfg, got[f]) - r
+        err = float(np.sqrt(np.mean(d * d))) / (float(r.std()) or 1.0)
         if err > worst or not worst_f:
             worst, worst_f = err, f
     return worst, worst_f
@@ -157,10 +160,10 @@ def report(label: str, name: str, cfg, outs: list, refs: dict,
     failed = [] if finite else ["non-finite fields"]
     for ref_name, ref_states in refs.items():
         tol, why = CHECKS[(name, ref_name)]
-        errs = [_max_rel_error(cfg, o, r) for o, r in zip(outs, ref_states)]
+        errs = [_rel_error(cfg, o, r) for o, r in zip(outs, ref_states)]
         steps = " ".join(f"step{i + 1}={e:.3e}({f})"
                          for i, (e, f) in enumerate(errs))
-        print(f"    max_rel_interior_err vs {ref_name}: {steps} "
+        print(f"    rms_err/std vs {ref_name}: {steps} "
               f"tol={tol:g}: {why}", flush=True)
         if not errs[-1][0] <= tol:
             failed.append(f"error {errs[-1][0]:.3e} vs {ref_name}")

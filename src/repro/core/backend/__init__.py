@@ -41,6 +41,7 @@ from .compile import (  # noqa: F401
     compile_program,
     compile_stencil,
     donation_supported,
+    jit_program,
     register_cache_clear,
 )
 

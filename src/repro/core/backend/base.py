@@ -52,6 +52,10 @@ class Backend(abc.ABC):
     #: hybrid chunk loop, ``batch="vmap:C,grid"``) on its own launch
     #: structure; False → ``"grid"`` modes degrade to vmap/scan
     member_grid: bool = False
+    #: True when the backend's programs hold custom kernels the chip's own
+    #: kernel compiler builds (Pallas → Mosaic/Triton), so jitting them
+    #: takes the attached chip's ``Hardware.kernel_step_options``
+    native_kernels: bool = False
 
     def resolve_hw(self, hardware: Hardware | str | None) -> Hardware:
         return resolve_hardware(hardware, default=self.default_hardware)

@@ -30,7 +30,7 @@ from typing import TYPE_CHECKING, Any, Callable, Mapping
 import jax
 import jax.numpy as jnp
 
-from ..hardware import Hardware
+from ..hardware import Hardware, detect_hardware
 from ..stencil.schedule import Schedule
 from .base import Backend, get_backend
 from .batching import AUTO, BatchSpec, pad_members, parse_batch
@@ -81,6 +81,24 @@ def pallas_interpret() -> bool:
     Lowerings read it when they build a kernel; nothing above them takes
     an ``interpret`` option."""
     return jax.default_backend() == "cpu"
+
+
+def jit_program(fn: Callable, backend: "str | Backend", *,
+                donate: bool = False) -> Callable:
+    """``jax.jit`` of a program built from ``backend``'s kernels — every
+    top-level jit of a compiled program goes through here: the input
+    fields dict (argument 0) is donated when asked and the platform honours
+    it (:func:`donation_supported`), and a program holding native kernels
+    takes the attached chip's ``Hardware.kernel_step_options`` (v5e: XLA's
+    VMEM memory-space assignment off).  JAX takes compiler options only on
+    an outermost jit, so a jitted program must not be nested in another."""
+    opts = None
+    if get_backend(backend).native_kernels and not pallas_interpret():
+        hw = detect_hardware()
+        opts = dict(hw.kernel_step_options) if hw else None
+    return jax.jit(fn, compiler_options=opts or None,
+                   donate_argnums=(0,) if donate and donation_supported()
+                   else ())
 
 
 def varying_zeros(shape, dtype, like=None) -> jax.Array:
@@ -365,11 +383,8 @@ def compile_program(program: "StencilProgram",
     fn: Callable = run
     donated = False
     if donate:
-        if donation_supported():
-            jitted = jax.jit(run, donate_argnums=(0,))
-            donated = True
-        else:
-            jitted = jax.jit(run)
+        jitted = jit_program(run, be, donate=True)
+        donated = donation_supported()
 
         @functools.wraps(run)
         def fn(fields: dict, params: dict | None = None) -> dict:

@@ -32,6 +32,7 @@ class PallasTPUBackend(Backend):
     scratch_temps = True
     #: this backend can place the member axis (and chunk loops) on its grid
     member_grid = True
+    native_kernels = True
 
     def compile_stencil(self, stencil: Stencil, dom: DomainSpec, *,
                         schedule: Schedule | None = None,

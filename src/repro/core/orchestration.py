@@ -64,7 +64,7 @@ def orchestrate(program_or_fn, *, backend: str = "jnp", hardware=None,
     (TPU/GPU); the sequential CPU path would warn and ignore it, so there
     the flag degrades to a plain ``jit``.
     """
-    from .backend import compile_program, donation_supported
+    from .backend import compile_program, jit_program
     from .graph import StencilProgram
 
     if isinstance(program_or_fn, StencilProgram):
@@ -72,6 +72,4 @@ def orchestrate(program_or_fn, *, backend: str = "jnp", hardware=None,
                              opt_level=opt_level)
     else:
         fn = program_or_fn
-    if donate and donation_supported():
-        return jax.jit(fn, donate_argnums=(0,))
-    return jax.jit(fn)
+    return jit_program(fn, backend, donate=donate)

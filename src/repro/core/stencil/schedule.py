@@ -26,6 +26,7 @@ import itertools
 from typing import Iterator
 
 from ..hardware import Hardware, resolve_hardware
+from .domain import DomainSpec
 from .ir import Direction, Stencil, expr_contains_level_search
 
 
@@ -163,13 +164,9 @@ def j_tile(stencil: Stencil, sched: Schedule) -> int:
     return sched.block_j if (sched.block_j and j_tileable(stencil)) else 0
 
 
-def _dims(dom_shape) -> tuple[int, int, int, int]:
-    """(nk, nj, ni, halo) of a DomainSpec, or of an interior (nk, nj, ni)
-    shape taken as halo-free."""
-    if hasattr(dom_shape, "halo"):
-        return dom_shape.nk, dom_shape.nj, dom_shape.ni, dom_shape.halo
-    nk, nj, ni = dom_shape
-    return nk, nj, ni, 0
+def _dims(dom: DomainSpec) -> tuple[int, int, int, int]:
+    """(nk, nj, ni, halo) of a DomainSpec."""
+    return dom.nk, dom.nj, dom.ni, dom.halo
 
 
 def _round_up(x: int, m: int) -> int:
@@ -181,9 +178,8 @@ def vmem_footprint(stencil: Stencil, sched: Schedule, dom_shape,
                    hw: Hardware | str | None = None) -> int:
     """Bytes of fast on-chip memory one kernel invocation holds under this
     schedule (VMEM on TPU; shared-memory tile on GPU); callers compare it
-    against ``hw.vmem_bytes``.  ``dom_shape`` is a
-    :class:`~repro.core.stencil.domain.DomainSpec` (or an interior
-    ``(nk, nj, ni)``, taken as halo-free).
+    against ``hw.vmem_bytes``.  ``dom_shape`` is the kernel's
+    :class:`~repro.core.stencil.domain.DomainSpec`.
 
     On TPU this counts the blocks the Pallas lowering allocates: each block
     spans the halo-padded plane (or a J tile of it, :func:`j_tile`), its

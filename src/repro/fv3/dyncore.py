@@ -34,10 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import StencilProgram, compile_program, donation_supported
-from repro.core.backend import register_cache_clear
+from repro.core import StencilProgram, compile_program
+from repro.core.backend import jit_program, register_cache_clear
 from repro.core.backend.batching import BatchSpec, parse_batch, scan_chunked
-from repro.core.hardware import detect_hardware
 from repro.core.stencil import DomainSpec
 from . import stencils as S
 from .halo import exchange_reference, make_halo_exchanger
@@ -519,16 +518,6 @@ def _remap_iteration(cfg, runners, params, halo_fn, state, metrics,
     return st
 
 
-def _kernel_step_options(backend: str) -> dict | None:
-    """XLA compiler options for a step holding the attached chip's Pallas
-    kernels (``Hardware.kernel_step_options``); None off a TPU and for
-    the jnp backend."""
-    hw = detect_hardware()
-    if hw is None or not str(backend).startswith("pallas"):
-        return None
-    return dict(hw.kernel_step_options) or None
-
-
 def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
                    metrics, params, counters, *, backend: str, unroll: bool,
                    donate: bool,
@@ -558,9 +547,7 @@ def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
     else:
         _step = _inner
 
-    jitted = jax.jit(
-        _step, donate_argnums=(0,) if donate and donation_supported() else (),
-        compiler_options=_kernel_step_options(backend))
+    jitted = jit_program(_step, backend, donate=donate)
 
     @functools.wraps(_step)
     def step(state: dict) -> dict:
@@ -855,7 +842,7 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
         in_specs=(dict.fromkeys(fields, spec),),
         out_specs=dict.fromkeys(fields, spec),
     )
-    jitted = jax.jit(sharded)
+    jitted = jit_program(sharded, backend)
 
     def step(state: dict) -> dict:
         return jitted(state)

@@ -69,7 +69,7 @@ def _lap(q: Field, out: Field):
 
 
 def test_feasible_schedules_tpu_vs_gpu_rules():
-    shape = (16, 256, 512)  # (nk, nj, ni)
+    shape = DomainSpec(ni=512, nj=256, nk=16, halo=1)
     tpu = list(feasible_schedules(_lap, shape, hw=TPU_V5E))
     gpu = list(feasible_schedules(_lap, shape, hw=P100))
     assert tpu and gpu
@@ -89,7 +89,7 @@ def test_feasible_schedules_tpu_vs_gpu_rules():
 
 
 def test_backend_heuristic_schedules_differ_by_hardware():
-    shape = (16, 128, 128)
+    shape = DomainSpec(ni=128, nj=128, nk=16, halo=1)
     tpu_sched = get_backend("pallas-tpu").heuristic_schedule(_lap, shape)
     gpu_sched = get_backend("pallas-gpu").heuristic_schedule(_lap, shape)
     assert tpu_sched.block_i == 0          # full IJ for halo reuse in VMEM
@@ -106,7 +106,7 @@ def _koff(q: Field, out: Field):
 def test_gpu_schedules_exist_for_k_offset_stencils():
     """K-offset stencils need whole-K blocks; the GPU rules must still
     enumerate (small IJ tiles, block_k=0), not come up empty."""
-    shape = (16, 64, 64)
+    shape = DomainSpec(ni=64, nj=64, nk=16, halo=2)
     gpu = list(feasible_schedules(_koff, shape, hw=P100))
     assert gpu, "GPU enumeration empty for k-offset stencil"
     assert all(s.block_k == 0 for s in gpu)

@@ -93,7 +93,9 @@ def test_nested_index_search_rejected_at_construction():
 
 def test_level_search_schedules_whole_column_only():
     for hw in ("tpu-v5e", "p100"):
-        for sched in feasible_schedules(S.interface_interp, (16, 16, 16),
+        for sched in feasible_schedules(S.interface_interp,
+                                        DomainSpec(ni=16, nj=16, nk=16,
+                                                   halo=6),
                                         hw=hw):
             assert sched.block_k == 0
 
@@ -303,7 +305,8 @@ def test_kblocked_schedules_enumerated_and_fit_vmem():
     assert model_cost(S.precompute_pe, whole, dom, tiny) == float("inf")
     assert model_cost(S.precompute_pe, scheds[0], dom, tiny) < float("inf")
     # non-blockable solvers never get blocked schedules
-    for s in feasible_schedules(S.tridiag_solve, (80, 16, 16),
+    for s in feasible_schedules(S.tridiag_solve,
+                                DomainSpec(ni=16, nj=16, nk=80, halo=6),
                                 hw="tpu-v5e"):
         assert s.block_k == 0
 

@@ -3,17 +3,20 @@
 Interpret mode hides what the TPU's kernel compiler (Mosaic) refuses: loads
 from memory it cannot read directly, dynamic slices of loaded values, and
 blocks larger than the scoped VMEM a kernel may use.  These tests compile
-single kernels at the production width — a C128 tile padded by the halo to
-140 × 140 points, 80 levels — for a described ``v5e`` topology, under the
-scoped-VMEM limit the descriptor gives every kernel.  ``vmem_footprint``
-must not count less than the scoped VMEM the compiler reports the kernel
-using, where that report is about the kernel's blocks: the schedule tuner
-trusts the footprint to keep blocks inside VMEM.
+kernels at the production width — a C128 tile padded by the halo to
+140 × 140 points, 80 levels — for a described ``v5e`` topology, the way
+the dycore does: through ``jit_program``, with the descriptor's kernel
+options (XLA's VMEM memory-space assignment off, so every kernel holds
+its own blocks) and its scoped-VMEM limit.  ``vmem_footprint`` counts
+those blocks, and the schedule tuner keeps them within ``vmem_bytes``;
+what the compiler reports beyond them is its own scratch for a
+statement's values, which must fit the headroom the limit leaves.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU compiler's library.
 """
 
+import dataclasses
 import re
 
 import jax
@@ -21,12 +24,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import TPU_V5E, compile_stencil
+from repro.core import TPU_V5E, compile_program, compile_stencil
 from repro.core.autotune import tune_stencil
 from repro.core.backend import compile as compile_mod
+from repro.core.backend import jit_program
 from repro.core.stencil import DomainSpec
 from repro.core.stencil.schedule import default_schedule, vmem_footprint
 from repro.fv3 import stencils as S
+from repro.fv3.dyncore import FV3Config, build_remap_program
 
 #: one C128 tile at production depth: 128 + 2 * 6 = 140 points per side
 DOM = DomainSpec(ni=128, nj=128, nk=80, halo=6)
@@ -50,22 +55,30 @@ def one_chip(topo):
 
 @pytest.fixture
 def native(monkeypatch):
-    """Lower Pallas kernels for the chip, not for the interpreter."""
+    """Lower Pallas kernels for the chip, not for the interpreter, and jit
+    them with the v5e's kernel options, as on an attached v5e."""
     monkeypatch.setattr(compile_mod, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(compile_mod, "detect_hardware", lambda: TPU_V5E)
 
 
-def _compile(stencil, sched, sharding, hw, n_members=None) -> str:
+def _specs(stencil_or_program, sharding, lead=()):
+    fields = {f: jax.ShapeDtypeStruct(
+        lead + DOM.padded_shape(stencil_or_program.is_interface(f)),
+        jnp.float32, sharding=sharding)
+        for f in stencil_or_program.fields}
+    params = {p: jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
+              for p in stencil_or_program.params}
+    return fields, params
+
+
+def _compile(stencil, sched, sharding, n_members=None) -> str:
     """Compiled HLO text of ``stencil`` under ``sched`` for one chip."""
     run = compile_stencil(stencil, DOM, backend="pallas-tpu", schedule=sched,
-                          hardware=hw, memoize=False, n_members=n_members,
-                          batch="grid")
+                          hardware=TPU_V5E, memoize=False,
+                          n_members=n_members, batch="grid")
     lead = (n_members,) if n_members else ()
-    fields = {f: jax.ShapeDtypeStruct(
-        lead + DOM.padded_shape(stencil.is_interface(f)), jnp.float32,
-        sharding=sharding) for f in stencil.fields}
-    params = {p: jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
-              for p in stencil.params}
-    return jax.jit(run).lower(fields, params).compile().as_text()
+    return jit_program(run, "pallas-tpu").lower(
+        *_specs(stencil, sharding, lead)).compile().as_text()
 
 
 def _kernel_vmem(text: str) -> list[int]:
@@ -78,20 +91,19 @@ def _kernel_vmem(text: str) -> list[int]:
 
 
 def _check(stencil, sched, sharding, n_members=None) -> str:
-    """Compile under the descriptor's own limit (as the dycore does), then
-    hold ``vmem_footprint`` to what the compiler reports using."""
-    text = _compile(stencil, sched, sharding, TPU_V5E, n_members)
+    """Compile as the dycore does, then hold the model to the compiler:
+    the modelled blocks fit the tuner's budget, and what the compiler
+    reports beyond them (its scratch for a statement's values) fits the
+    headroom between that budget and the kernel's scoped-VMEM limit."""
+    text = _compile(stencil, sched, sharding, n_members)
+    # the kernel options took: XLA placed no array in VMEM (memory space 1)
+    assert "S(1)" not in text
     used = _kernel_vmem(text)
     assert used, "no Mosaic kernel in the compiled text"
     fp = vmem_footprint(stencil, sched, DOM, hw=TPU_V5E)
     assert fp <= TPU_V5E.vmem_bytes
-    # a report of at least one whole (tiled) field array is the compiler
-    # staging an operand of this lone kernel whole in VMEM, not its blocks
-    nk, nj, ni = DOM.padded_shape(interface=True)
-    whole = ((n_members or 1) * nk * -(-nj // TPU_V5E.sublane)
-             * TPU_V5E.sublane * -(-ni // TPU_V5E.lane) * TPU_V5E.lane * 4)
-    for u in used:
-        assert u <= fp or u >= whole, (used, fp)
+    headroom = TPU_V5E.vmem_limit_bytes - TPU_V5E.vmem_bytes
+    assert all(u - fp <= headroom for u in used), (used, fp)
     return text
 
 
@@ -131,3 +143,32 @@ def test_member_grid_kernel_compiles(one_chip, native):
     sched = default_schedule(S.fx_ppm, DOM)
     text = _check(S.fx_ppm, sched, one_chip, n_members=2)
     assert len(_kernel_vmem(text)) == 1
+
+
+def test_remap_program_needs_the_raised_limit(one_chip, native):
+    """The opt-3 vertical remap at C128 x 80, compiled as the dycore
+    compiles it (``compile_program``, then ``jit_program``, which also
+    proves the compile path takes the kernel options): its fused
+    whole-column kernels hold a statement's values beside their blocks,
+    more than the compiler's default 16 MiB of scoped VMEM, so they compile
+    under the descriptor's raised limit and not without it."""
+    cfg = FV3Config(npx=DOM.ni, nk=DOM.nk)
+    prog = build_remap_program(cfg, DOM)
+
+    def lower(hw):
+        run = compile_program(prog, "pallas-tpu", hardware=hw, opt_level=3)
+        fields = {f: jax.ShapeDtypeStruct(
+            DOM.padded_shape(prog.fields[f].interface), jnp.float32,
+            sharding=one_chip) for f in run.input_fields}
+        params = {p: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+                  for n in run.program.all_nodes() for p in n.stencil.params}
+        return jit_program(run, "pallas-tpu").lower(fields, params)
+
+    text = lower(TPU_V5E).compile().as_text()
+    assert "S(1)" not in text
+    used = _kernel_vmem(text)
+    assert TPU_V5E.vmem_bytes < max(used) <= TPU_V5E.vmem_limit_bytes
+    default = dataclasses.replace(TPU_V5E, name="tpu-v5e-default-limit",
+                                  vmem_limit_bytes=0)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="scoped vmem"):
+        lower(default).compile()

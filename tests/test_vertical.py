@@ -251,7 +251,7 @@ def test_remap_opt3_matches_opt0():
 def test_interface_schedules_never_tile_k():
     from repro.core.stencil import default_schedule, heuristic_schedule
 
-    dom_shape = (8, 16, 16)
+    dom_shape = DomainSpec(ni=16, nj=16, nk=8, halo=1)
     for hw in ("tpu-v5e", "p100"):
         for sched in feasible_schedules(_iface_diff, dom_shape, hw=hw):
             assert sched.block_k == 0, \
