@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import hashlib
 from typing import TYPE_CHECKING, Any, Callable, Mapping
 
 import jax
@@ -99,6 +100,31 @@ def jit_program(fn: Callable, backend: "str | Backend", *,
     return jax.jit(fn, compiler_options=opts or None,
                    donate_argnums=(0,) if donate and donation_supported()
                    else ())
+
+
+#: longest stencil name a compiled program's metadata carries whole
+TRACE_NAME_MAX = 128
+
+
+def trace_name(name: str) -> str:
+    """A stencil's name, or a node's label (``<name>#<n>``), as scopes and
+    kernels show it: whole up to ``TRACE_NAME_MAX`` characters, else cut
+    and ended by a hash of the whole.  Fusing many stencils joins their
+    names, and XLA drops the enclosing scopes from an op whose op_name
+    would pass about a thousand characters."""
+    stem, sep, tag = name.partition("#")
+    if len(stem) > TRACE_NAME_MAX:
+        digest = hashlib.sha1(stem.encode()).hexdigest()[:8]
+        stem = f"{stem[:TRACE_NAME_MAX - 9]}~{digest}"
+    return stem + sep + tag
+
+
+def kernel_jit(fn: Callable, name: str) -> Callable:
+    """``jax.jit`` of one stencil's runner under the stencil's name, so its
+    ops read ``jit(<name>)`` in the compiled program's metadata and in a
+    profile (every runner function is otherwise called ``run``)."""
+    fn.__name__ = fn.__qualname__ = trace_name(name)
+    return jax.jit(fn)
 
 
 def varying_zeros(shape, dtype, like=None) -> jax.Array:
@@ -346,7 +372,10 @@ def compile_program(program: "StencilProgram",
         for i, (n, r) in enumerate(runners):
             ins = {f: env[f] for f in n.stencil.fields}
             ps = {p: params[p] for p in n.stencil.params}
-            env.update(r(ins, ps))
+            # the node's label names its ops in the compiled program's
+            # metadata (and so in a profile)
+            with jax.named_scope(trace_name(n.label)):
+                env.update(r(ins, ps))
             for f in drop_after[i]:
                 env.pop(f, None)
         return env

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..stencil.domain import DomainSpec
-from .compile import varying_zeros
+from .compile import kernel_jit, varying_zeros
 from ..stencil.ir import (
     Assign,
     BinOp,
@@ -320,4 +320,4 @@ def compile_jnp(stencil: Stencil, dom: DomainSpec, *, dtype=jnp.float32):
                 _apply_vertical(comp, env, dom, stencil)
         return {f: env[f] for f in stencil.written() if f in stencil.fields}
 
-    return jax.jit(run)
+    return kernel_jit(run, stencil.name)

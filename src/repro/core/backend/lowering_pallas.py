@@ -856,14 +856,16 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
     return kernel, grid, in_specs, out_specs, written, temps, carried
 
 
-def _pallas_call(kernel, *, vmem_limit: int, **kw):
-    """``pl.pallas_call`` in the platform's mode, with the kernel's
-    scoped-VMEM limit (compiled kernels only; 0 keeps the compiler's
-    default)."""
+def _pallas_call(kernel, *, name: str, vmem_limit: int, **kw):
+    """``pl.pallas_call`` in the platform's mode, named after its stencil
+    (the Mosaic kernel's name, as ``trace_name`` gives it), with the
+    kernel's scoped-VMEM limit (compiled kernels only; 0 keeps the
+    compiler's default)."""
     interpret = _compile.pallas_interpret()
     params = (pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
               if vmem_limit and not interpret else None)
     return pl.pallas_call(kernel, interpret=interpret,
+                          name=_compile.trace_name(name),
                           compiler_params=params, **kw)
 
 
@@ -905,11 +907,11 @@ def _compile_kblocked(stencil: Stencil, dom: DomainSpec, sched: Schedule,
         outs = _pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shapes, scratch_shapes=scratch,
-            vmem_limit=vmem_limit,
+            name=stencil.name, vmem_limit=vmem_limit,
         )(*args)
         return dict(zip(written, outs))
 
-    return jax.jit(run)
+    return _compile.kernel_jit(run, stencil.name)
 
 
 # ---------------------------------------------------------------------------
@@ -1009,11 +1011,11 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
             outs = _pallas_call(
                 kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
                 out_shape=out_shapes, scratch_shapes=scratch,
-                vmem_limit=vmem_limit,
+                name=stencil.name, vmem_limit=vmem_limit,
             )(*args)
             return dict(zip(written, outs[:len(written)]))
 
-        return jax.jit(run)
+        return _compile.kernel_jit(run, stencil.name)
 
     # horizontal stencil — inline offset-read temporaries (PPM's br[-1]),
     # then possibly split regions into separate kernels
@@ -1048,10 +1050,11 @@ def compile_pallas(stencil: Stencil, dom: DomainSpec, *,
                           for w in written]
             outs = _pallas_call(
                 kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-                out_shape=out_shapes, vmem_limit=vmem_limit,
+                out_shape=out_shapes, name=stencil.name,
+                vmem_limit=vmem_limit,
             )(*args)
             for w, o in zip(written, outs):
                 cur[w] = o
         return {w: cur[w] for w in stencil.written() if w in stencil.fields}
 
-    return jax.jit(run)
+    return _compile.kernel_jit(run, stencil.name)

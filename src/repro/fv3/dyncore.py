@@ -25,8 +25,10 @@ inside one jitted step — a single dispatch per physics step.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import time
 import warnings
 from typing import Callable
 
@@ -341,6 +343,29 @@ def _build_programs(cfg: FV3Config, dom: DomainSpec):
             build_remap_program(cfg, dom))
 
 
+@contextlib.contextmanager
+def _timed_build(name: str, seconds: dict):
+    """A program's build in a step factory (rewrite ladder, tuning, runner
+    construction): its host seconds added to ``seconds[name]``."""
+    t = time.perf_counter()
+    yield
+    seconds[name] = seconds.get(name, 0.0) + time.perf_counter() - t
+
+
+def _build_seconds(t0: float, progs, runners, seconds: dict) -> dict:
+    """``step.build_seconds``: ``total`` host seconds from the factory's
+    entry (``t0``) to now, ``programs`` each program's build, and
+    ``rewrite`` the rewrite ladder's share of it (its ``PassStats``,
+    verification included)."""
+    rewrite = {}
+    for p, r in zip(progs, runners):
+        rep = r.opt_report
+        rewrite[p.name] = 0.0 if rep is None else rep.input_verify_seconds \
+            + sum(s.seconds + s.verify_seconds for s in rep.passes)
+    return {"total": time.perf_counter() - t0, "programs": dict(seconds),
+            "rewrite": rewrite}
+
+
 def _make_programs(cfg: FV3Config, dom: DomainSpec, backend: str,
                    opt_level: int, hardware=None,
                    n_members: int | None = None, batch: str = "vmap",
@@ -352,14 +377,17 @@ def _make_programs(cfg: FV3Config, dom: DomainSpec, backend: str,
     ``n_members``/``batch`` thread the ensemble axis into every program;
     ``verify`` selects the static-verifier mode (``None`` resolves from
     ``$REPRO_VERIFY`` / the pytest-CI default, see
-    :func:`repro.core.analysis.resolve_verify_mode`)."""
+    :func:`repro.core.analysis.resolve_verify_mode`).  Returns the
+    programs, their runners and each program's build seconds."""
     progs = _build_programs(cfg, dom)
-    runners = tuple(
-        compile_program(p, backend, hardware=hardware,
-                        opt_level=opt_level, n_members=n_members,
-                        batch=batch, verify=verify)
-        for p in progs)
-    return progs, runners
+    seconds: dict[str, float] = {}
+    runners = []
+    for p in progs:
+        with _timed_build(p.name, seconds):
+            runners.append(compile_program(
+                p, backend, hardware=hardware, opt_level=opt_level,
+                n_members=n_members, batch=batch, verify=verify))
+    return progs, tuple(runners), seconds
 
 
 def _metric_terms(cfg: FV3Config, shape, dtype=jnp.float32) -> dict:
@@ -375,6 +403,22 @@ def _csw_inputs(src, metrics):
     return {"u": src["u"], "v": src["v"], "delp": src["delp"],
             "pt": src["pt"], "w": src["w"],
             "cosa": metrics["cosa"], "sina": metrics["sina"]}
+
+
+#: The step's layers as named scopes: every program call runs under its
+#: program's scope (the program name with non-word characters replaced) and
+#: every halo exchange under ``halo_exchange``, so each op of the compiled
+#: step carries its layer in its ``op_name`` metadata (and, inside a
+#: program, the label of its stencil node: see ``compile_program``).
+CSW_SCOPE, DSW_SCOPE, TRACER_SCOPE, REMAP_SCOPE = (
+    "c_sw_riem", "d_sw", "tracer_2d", "vertical_remap")
+HALO_SCOPE = "halo_exchange"
+STEP_SCOPES = (CSW_SCOPE, DSW_SCOPE, TRACER_SCOPE, REMAP_SCOPE, HALO_SCOPE)
+
+
+def _exchange(halo_fn, st, names):
+    with jax.named_scope(HALO_SCOPE):
+        return halo_fn(st, names)
 
 
 def _acoustic_iteration(cfg, runners, params, halo_fn, state, metrics,
@@ -393,24 +437,28 @@ def _acoustic_iteration(cfg, runners, params, halo_fn, state, metrics,
     if overlap is not None and overlap[0] is not None and overlap[1] is not None:
         ov_csw, ov_dsw, _ = overlap
         st = dict(state)
-        ex = halo_fn(st, list(STATE_FIELDS))          # ppermute rounds
-        out = ov_csw(_csw_inputs(st, metrics), _csw_inputs(ex, metrics),
-                     params)                          # interior ∥ exchange
+        ex = _exchange(halo_fn, st, list(STATE_FIELDS))   # ppermute rounds
+        with jax.named_scope(CSW_SCOPE):
+            out = ov_csw(_csw_inputs(st, metrics), _csw_inputs(ex, metrics),
+                         params)                      # interior ∥ exchange
         st = ex
         st["w"] = out["w"]
-        delpc = halo_fn({**st, "delpc": out["delpc"]}, ["delpc"])["delpc"]
+        delpc = _exchange(halo_fn, {**st, "delpc": out["delpc"]},
+                          ["delpc"])["delpc"]
         dsw_stale = {"u": st["u"], "v": st["v"], "delp": st["delp"],
                      "pt": st["pt"], "delpc": out["delpc"]}
         dsw_fresh = {**dsw_stale, "delpc": delpc}
-        out2 = ov_dsw(dsw_stale, dsw_fresh, params)   # interior ∥ exchange
+        with jax.named_scope(DSW_SCOPE):
+            out2 = ov_dsw(dsw_stale, dsw_fresh, params)   # interior ∥ exchange
         st["u"], st["v"] = out2["u"], out2["v"]
         st["delp"], st["pt"] = out2["delp_out"], out2["pt_out"]
         return st
 
     run_csw, run_dsw = runners[0], runners[1]
     st = dict(state)
-    st = halo_fn(st, list(STATE_FIELDS))
-    out = run_csw(_csw_inputs(st, metrics), params)
+    st = _exchange(halo_fn, st, list(STATE_FIELDS))
+    with jax.named_scope(CSW_SCOPE):
+        out = run_csw(_csw_inputs(st, metrics), params)
     st["w"] = out["w"]
     if skip_delpc_exchange:
         # recompute-vs-exchange applied: c_sw computed delpc on a one-cell
@@ -420,10 +468,12 @@ def _acoustic_iteration(cfg, runners, params, halo_fn, state, metrics,
     else:
         # d_sw's Smagorinsky reads delpc at extent (1,1) — one scalar
         # exchange
-        delpc = halo_fn({**st, "delpc": out["delpc"]}, ["delpc"])["delpc"]
+        delpc = _exchange(halo_fn, {**st, "delpc": out["delpc"]},
+                          ["delpc"])["delpc"]
     dsw_in = {"u": st["u"], "v": st["v"], "delp": st["delp"],
               "pt": st["pt"], "delpc": delpc}
-    out2 = run_dsw(dsw_in, params)
+    with jax.named_scope(DSW_SCOPE):
+        out2 = run_dsw(dsw_in, params)
     st["u"], st["v"] = out2["u"], out2["v"]
     st["delp"], st["pt"] = out2["delp_out"], out2["pt_out"]
     return st
@@ -492,26 +542,29 @@ def _remap_iteration(cfg, runners, params, halo_fn, state, metrics,
 
     st = _scan_substeps(acoustic_body, dict(state), cfg.n_split, unroll)
     if overlap is not None and overlap[2] is not None:
-        ex = halo_fn(st, ["u", "v", *cfg.tracers])
+        ex = _exchange(halo_fn, st, ["u", "v", *cfg.tracers])
         stale = {"u": st["u"], "v": st["v"],
                  **{q: st[q] for q in cfg.tracers}}
         fresh = {"u": ex["u"], "v": ex["v"],
                  **{q: ex[q] for q in cfg.tracers}}
-        out = overlap[2](stale, fresh, params)        # interior ∥ exchange
+        with jax.named_scope(TRACER_SCOPE):
+            out = overlap[2](stale, fresh, params)    # interior ∥ exchange
         st = ex
     else:
-        st = halo_fn(st, ["u", "v", *cfg.tracers])
+        st = _exchange(halo_fn, st, ["u", "v", *cfg.tracers])
         trc_in = {"u": st["u"], "v": st["v"]}
         for q in cfg.tracers:
             trc_in[q] = st[q]
-        out = run_trc(trc_in, params)
+        with jax.named_scope(TRACER_SCOPE):
+            out = run_trc(trc_in, params)
     for q in cfg.tracers:
         st[q] = out[f"{q}_out"]
     # vertical remap back to reference levels — a compiled stencil program
     # like every other motif (interface fields, pass manager, tuning cache)
     names = (*REMAP_FIELDS, *cfg.tracers)
-    rout = run_remap({"delp": st["delp"],
-                      **{q: st[q] for q in names}}, params)
+    with jax.named_scope(REMAP_SCOPE):
+        rout = run_remap({"delp": st["delp"],
+                          **{q: st[q] for q in names}}, params)
     st["delp"] = rout["delp_out"]
     for q in names:
         st[q] = rout[f"{q}_out"]
@@ -552,7 +605,8 @@ def _assemble_step(cfg: FV3Config, progs, runners, runners_v, halo_fn,
     @functools.wraps(_step)
     def step(state: dict) -> dict:
         counters["step_calls"] += 1
-        return jitted(state)
+        with jax.profiler.TraceAnnotation("repro.step"):
+            return jitted(state)
 
     step.counters = counters
     # ahead-of-time: step.lower(state).compile() — compile time apart from
@@ -589,12 +643,13 @@ def make_step_sequential(cfg: FV3Config, *, backend: str = "jnp",
     The returned callable exposes ``opt_report`` (per-program pass-pipeline
     reports covering acoustic + tracer + remap), ``n_kernels`` and
     ``counters`` (trace/dispatch instrumentation used by the
-    dispatch-count tests and benchmarks).
+    dispatch-count tests and benchmarks) and ``build_seconds`` (host
+    seconds of the factory, per program and for the rewrite ladder).
     """
+    t0 = time.perf_counter()
     dom = cfg.seq_dom()
-    progs, runners = _make_programs(cfg, dom, backend,
-                                    _resolve_opt_level(optimize, opt_level),
-                                    hardware)
+    progs, runners, seconds = _make_programs(
+        cfg, dom, backend, _resolve_opt_level(optimize, opt_level), hardware)
     params = default_params(cfg)
     counters = {"acoustic_traces": 0, "runner_dispatches": 0,
                 "step_calls": 0}
@@ -602,9 +657,11 @@ def make_step_sequential(cfg: FV3Config, *, backend: str = "jnp",
     # cosa/sina hoisted out of the scan body: constants are built once per
     # step closure, not re-materialized every acoustic substep
     metrics = _metric_terms(cfg, (6,) + dom.padded_shape())
-    return _assemble_step(cfg, progs, runners, runners_v,
+    step = _assemble_step(cfg, progs, runners, runners_v,
                           _reference_halo_fn(cfg), metrics, params, counters,
                           backend=backend, unroll=unroll, donate=donate)
+    step.build_seconds = _build_seconds(t0, progs, runners, seconds)
+    return step
 
 
 def make_step_ensemble(cfg: FV3Config, n_members: int, *,
@@ -640,6 +697,7 @@ def make_step_ensemble(cfg: FV3Config, n_members: int, *,
     the step M-wide and pushes the chunk loop into each Pallas kernel's
     outermost grid axis.
     """
+    t0 = time.perf_counter()
     if batch is None:
         batch = "grid" if str(backend).startswith("pallas") else "vmap"
     spec = parse_batch(batch)
@@ -654,10 +712,9 @@ def make_step_ensemble(cfg: FV3Config, n_members: int, *,
             member_chunks = (n_members, C)
             prog_members, prog_batch = C, BatchSpec(mode=spec.mode)
     dom = cfg.seq_dom()
-    progs, runners = _make_programs(cfg, dom, backend,
-                                    _resolve_opt_level(optimize, opt_level),
-                                    hardware, n_members=prog_members,
-                                    batch=prog_batch)
+    progs, runners, seconds = _make_programs(
+        cfg, dom, backend, _resolve_opt_level(optimize, opt_level), hardware,
+        n_members=prog_members, batch=prog_batch)
     params = default_params(cfg)
     counters = {"acoustic_traces": 0, "runner_dispatches": 0,
                 "step_calls": 0}
@@ -677,6 +734,7 @@ def make_step_ensemble(cfg: FV3Config, n_members: int, *,
         (runners[0].member_chunk if n_members else None)
     step.n_chunks = (-(-n_members // member_chunks[1])
                      if member_chunks else runners[0].n_chunks)
+    step.build_seconds = _build_seconds(t0, progs, runners, seconds)
     return step
 
 
@@ -733,6 +791,7 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     """
     from jax.sharding import PartitionSpec as P
 
+    t0 = time.perf_counter()
     if ensemble:
         warnings.warn(
             "make_step_distributed(ensemble=True) is deprecated; pass "
@@ -764,18 +823,21 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     nl, h, nk = cfg.n_local, cfg.halo, cfg.nk
 
     memb = {"n_members": ml, "batch": batch} if ml > 1 else {}
+    seconds: dict[str, float] = {}
     # the remap program is purely vertical (no horizontal reads), so it
     # never participates in halo/compute overlap — compile it plain
-    run_remap = compile_program(progs[3], backend, hardware=hardware,
-                                opt_level=lvl, **memb)
+    with _timed_build(progs[3].name, seconds):
+        run_remap = compile_program(progs[3], backend, hardware=hardware,
+                                    opt_level=lvl, **memb)
     ov = None
     if overlap and ml == 1:
-        cands = tuple(
-            make_overlapped_runner(p, backend=backend, hardware=hardware,
-                                   opt_level=lvl)
-            for p in progs[:3])
+        cands = []
+        for p in progs[:3]:
+            with _timed_build(p.name, seconds):
+                cands.append(make_overlapped_runner(
+                    p, backend=backend, hardware=hardware, opt_level=lvl))
         if all(c is not None for c in cands):
-            ov = cands
+            ov = tuple(cands)
     skip_delpc = False
     if ov is None and lvl >= 4:
         # recompute-vs-exchange: widen c_sw so delpc is valid on a one-cell
@@ -803,10 +865,12 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
         # fallback runners the overlap branch never calls
         runners = tuple(c.full_run for c in ov) + (run_remap,)
     else:
-        runners = tuple(
-            compile_program(p, backend, hardware=hardware,
-                            opt_level=lvl, **memb)
-            for p in progs[:3]) + (run_remap,)
+        built = []
+        for p in progs[:3]:
+            with _timed_build(p.name, seconds):
+                built.append(compile_program(p, backend, hardware=hardware,
+                                             opt_level=lvl, **memb))
+        runners = tuple(built) + (run_remap,)
 
     def halo_fn(st, names):
         vec = [("u", "v")] if ("u" in names and "v" in names) else []
@@ -845,7 +909,8 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     jitted = jit_program(sharded, backend)
 
     def step(state: dict) -> dict:
-        return jitted(state)
+        with jax.profiler.TraceAnnotation("repro.step"):
+            return jitted(state)
 
     step.n_members = n_members
     step.members_per_group = ml
@@ -853,4 +918,5 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     step.member_chunk = runners[0].member_chunk if ml > 1 else None
     step.overlapped = ov is not None
     step.delpc_exchange_skipped = skip_delpc
+    step.build_seconds = _build_seconds(t0, progs, runners, seconds)
     return step

@@ -172,3 +172,36 @@ def test_remap_program_needs_the_raised_limit(one_chip, native):
                                   vmem_limit_bytes=0)
     with pytest.raises(jax.errors.JaxRuntimeError, match="scoped vmem"):
         lower(default).compile()
+
+
+@pytest.mark.parametrize("members", [1, 2])
+def test_step_ops_name_their_layers_and_kernels(one_chip, native, members):
+    """A whole Pallas step (C12, opt 3) compiled for the chip: every op
+    falls under one layer scope and, inside a program, one stencil node,
+    and each Mosaic kernel's instruction is named after the node's stencil
+    (what a profile of the step shows)."""
+    from _hlo_scopes import check
+    from repro.fv3.dyncore import (
+        STEP_SCOPES, all_state_fields, make_step_ensemble,
+        make_step_sequential,
+    )
+    from test_trace_scopes import CFG
+
+    if members > 1:
+        step = make_step_ensemble(CFG, members, backend="pallas-tpu",
+                                  opt_level=3)
+    else:
+        step = make_step_sequential(CFG, backend="pallas-tpu", opt_level=3)
+    lead = (members, 6) if members > 1 else (6,)
+    shape = lead + CFG.seq_dom().padded_shape()
+    state = {f: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+             for f in all_state_fields(CFG)}
+    text = step.lower(state).compile().as_text()
+    per_layer, nodes = check(text)
+    assert set(per_layer) == set(STEP_SCOPES), per_layer
+    kernels = re.findall(r"^\s+(?:ROOT )?%(\S+) = .*"
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert kernels
+    for name in kernels:
+        stencil = nodes[name].rsplit("#", 1)[0]
+        assert name.rsplit(".", 1)[0] == re.sub(r"\W", "_", stencil), name
