@@ -102,26 +102,34 @@ class _Column:
     """A field's whole K column over one horizontal window, read a level at
     a time: ``row(s)`` is level ``s`` (a traced index is fine).  Ref-backed
     columns index the ref at the level — Mosaic has no dynamic slice of a
-    loaded value; only kernel-local values fall back to one."""
+    loaded value; only kernel-local values fall back to one.  ``whole()``
+    loads every level at once."""
 
-    def __init__(self, shape, row):
+    def __init__(self, shape, dtype, row, whole):
         self.shape = tuple(shape)
         self.ndim = len(self.shape)
+        self.dtype = dtype
         self.row = row
+        self.whole = whole
 
     @classmethod
     def of_ref(cls, ref, jsl, isl):
         lead = ref.shape[:-3]
         shape = lead + (ref.shape[-3],) + _window_shape(ref, jsl, isl)
+
+        def whole():
+            return ref[..., jsl, isl]
         if lead:
-            return cls(shape, lambda s: ref[:, s, jsl, isl])
-        return cls(shape, lambda s: ref[s, jsl, isl])
+            return cls(shape, ref.dtype, lambda s: ref[:, s, jsl, isl], whole)
+        return cls(shape, ref.dtype, lambda s: ref[s, jsl, isl], whole)
 
     @classmethod
     def of_value(cls, col):
         # K sits at axis -3 so leading member-chunk dims ride through
-        return cls(col.shape, lambda s: jax.lax.dynamic_index_in_dim(
-            col, s, col.ndim - 3, keepdims=False))
+        return cls(col.shape, col.dtype,
+                   lambda s: jax.lax.dynamic_index_in_dim(
+                       col, s, col.ndim - 3, keepdims=False),
+                   lambda: col)
 
 
 def _window_shape(ref, jsl: slice, isl: slice):
@@ -129,11 +137,133 @@ def _window_shape(ref, jsl: slice, isl: slice):
             len(range(*isl.indices(ref.shape[-1]))))
 
 
-def _march_search(e: LevelSearch, read, params, read_col, nk: int):
-    """Lower a LevelSearch as an in-kernel *marching loop*: one
-    ``fori_loop`` walk over the source layers, accumulating the bracketing
-    values of every FoundLevel access with selects — no gathers, so the
-    loop maps onto the VPU on real TPUs.  O(1) trace size in nk."""
+#: vector registers one row block's found-level accumulators may hold: a
+#: quarter of the 64, so the block's targets, the level's fresh values and
+#: the compare masks fit beside them (on a v5e, twice as many ran the remap
+#: 30% slower)
+_BAND_VREGS = 16
+
+
+def band_rows(row_shape, n_found: int) -> int:
+    """Target rows per block of the band-limited search: as many as keep
+    the ``n_found`` accumulators of one block within :data:`_BAND_VREGS`
+    vector registers, given the shape of one target row (leading member
+    dims, then the (J, I) window; 32-bit values, (8, 128) per register)."""
+    *lead, nj, ni = row_shape
+    vregs = math.prod(lead) * -(-nj // 8) * -(-ni // 128)
+    return max(1, _BAND_VREGS // (vregs * max(1, n_found)))
+
+
+def _level_reduce(x, red):
+    """``red`` over every axis but the level axis (-3): a (K, 1) column."""
+    x = red(red(x, axis=-1), axis=-1, keepdims=True)
+    return red(x, axis=tuple(range(x.ndim - 2))) if x.ndim > 2 else x
+
+
+def _live_fill(x, live, fill):
+    """``x`` with the cells outside the live window set to ``fill``."""
+    if live is None:
+        return x
+    return jnp.where(live, x, jnp.asarray(fill, x.dtype))
+
+
+def _band(cmax, cmin, tmin, tmax, lo: int):
+    """The source layers ``[s_lo, s_hi]`` that can bracket a block of
+    targets spanning ``[tmin, tmax]`` over the window, from the per-level
+    max and min of the compared levels ``lo+1 .. hi-1``.  On non-decreasing
+    columns every layer up to ``s_lo`` lies at or below every target, and
+    every layer past ``s_hi`` above every target, so a march that starts at
+    ``s_lo`` and stops at ``s_hi`` selects what the march over all of
+    ``[lo, hi)`` selects."""
+    return (lo + jnp.sum(cmax <= tmin, dtype=jnp.int32),
+            lo + jnp.sum(cmin <= tmax, dtype=jnp.int32))
+
+
+def _block_bands(col, target_rows, live, lo: int, hi: int, rows: int):
+    """``[(r0, r1, s_lo, s_hi)]`` for the blocks of ``rows`` target rows
+    (row axis -3 of ``target_rows``) against the coordinate column ``col``,
+    over the live cells of the window: the one bound code the kernel and
+    :func:`search_band_share` share."""
+    c = col[..., lo + 1:hi, :, :]
+    # the contract the band rests on: every live column non-decreasing
+    # (and NaN-free) over the compared levels.  A window that breaks it
+    # takes extremes that band every block to all of [lo, hi-1]: a NaN
+    # maximum is at or below no target, -inf at or below every one.
+    ok = (c[..., 1:, :, :] >= c[..., :-1, :, :]) if c.shape[-3] > 1 \
+        else c == c
+    if live is not None:
+        ok = ok | ~live
+    mono = jnp.sum(~ok, dtype=jnp.int32) == 0
+    cmax = jnp.where(mono, _level_reduce(_live_fill(c, live, -jnp.inf),
+                                         jnp.max), jnp.nan)
+    cmin = jnp.where(mono, _level_reduce(_live_fill(c, live, jnp.inf),
+                                         jnp.min), -jnp.inf)
+    # a NaN target widens its block's band to the whole column
+    t = target_rows
+    nan = t != t
+    tlo = _level_reduce(
+        _live_fill(jnp.where(nan, -jnp.inf, t), live, jnp.inf), jnp.min)
+    thi = _level_reduce(
+        _live_fill(jnp.where(nan, jnp.inf, t), live, -jnp.inf), jnp.max)
+    out = []
+    for r0 in range(0, t.shape[-3], rows):
+        r1 = min(r0 + rows, t.shape[-3])
+        tmin = jnp.min(tlo[r0:r1], axis=0, keepdims=True)
+        tmax = jnp.max(thi[r0:r1], axis=0, keepdims=True)
+        out.append((r0, r1) + _band(cmax, cmin, tmin, tmax, lo))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _marched_pairs(coord, target, lo: int, hi: int, rows: int):
+    """Pairs the band-limited march walks in each window of a stack."""
+    def one(c, t):
+        return sum((r1 - r0) * (s_hi - s_lo) for r0, r1, s_lo, s_hi
+                   in _block_bands(c, t, None, lo, hi, rows))
+    return jax.vmap(one)(coord, target)
+
+
+def search_band_share(coord, target, window, *, lo: int, hi: int,
+                      n_found: int):
+    """Share of the level search's marched (target row x source layer)
+    pairs that the band-limited march walks, over the full march's
+    ``rows * (hi - lo - 1)``: plain jnp on the arrays a search reads, with
+    the kernel's own bound code.
+
+    ``coord`` and ``target`` are ``(..., K, J, I)`` (leading axes: tiles or
+    members, each its own window); ``window = (bj, bi)`` tiles the last two
+    axes as the kernel's blocks do (ragged tiles at the far ends);
+    ``n_found`` distinct FoundLevel accesses set the rows per block."""
+    coord, target = jnp.asarray(coord), jnp.asarray(target)
+    target = jnp.broadcast_to(target, coord.shape[:-3]
+                              + target.shape[-3:])
+    nrows = target.shape[-3]
+    bj, bi = window
+    rows = band_rows((bj, bi), n_found)
+    marched = 0
+    for j0 in range(0, coord.shape[-2], bj):
+        for i0 in range(0, coord.shape[-1], bi):
+            win = (Ellipsis, slice(j0, j0 + bj), slice(i0, i0 + bi))
+            c, t = coord[win], target[win]
+            c = c.reshape((-1,) + c.shape[-3:])
+            t = t.reshape((-1,) + t.shape[-3:])
+            marched += int(jnp.sum(_marched_pairs(c, t, lo, hi, rows)))
+    n_win = math.prod(coord.shape[:-3]) * -(-coord.shape[-2] // bj) \
+        * -(-coord.shape[-1] // bi)
+    return marched / (n_win * nrows * (hi - lo - 1))
+
+
+def _march_search(e: LevelSearch, read, params, read_col, nk: int,
+                  live=None):
+    """Lower a LevelSearch as in-kernel *marching loops*, band-limited:
+    the target rows split into static blocks (:func:`band_rows`), and each
+    block's ``fori_loop`` walks only the source layers that can bracket
+    its targets over the window (:func:`_band`), accumulating the
+    bracketing values of every FoundLevel access with selects — no
+    gathers, so the loops map onto the VPU on real TPUs.  The bounds come
+    from reductions of the coordinate column and the target over the live
+    window (``live``: the cells the statement writes; None for all); a
+    non-monotone window marches every layer.  Trace size O(nk / rows)."""
     if read_col is None or nk is None:
         raise NotImplementedError(
             "LevelSearch requires whole-column blocks (no read_col here)")
@@ -170,21 +300,43 @@ def _march_search(e: LevelSearch, read, params, read_col, nk: int):
 
         def lift(r):
             return r
+    target = jnp.broadcast_to(target, shape)
+    # a target with a row axis (whole-column statements) is blocked along
+    # it; a per-level target (inside a marching body) is one block
+    has_rows = len(shape) == cwin.ndim
 
-    def vals_at(s):
+    def vals_at(s, shp):
         return {(fl.name, fl.di, fl.dj, fl.dk): jnp.broadcast_to(
-                    lift(cols[(fl.name, fl.di, fl.dj)].row(s + fl.dk)),
-                    shape)
+                    lift(cols[(fl.name, fl.di, fl.dj)].row(s + fl.dk)), shp)
                 for fl in finds}
 
-    def body(s, acc):
-        take = lift(cwin.row(s)) <= target
-        fresh = vals_at(s)
-        return {k: jnp.where(take, fresh[k], acc[k]) for k in acc}
+    def body(s, carry):
+        # one function for every block (the band's first layer and the
+        # targets ride in the carry), so the loop body is traced once per
+        # block shape; the first layer is taken whatever it compares
+        s_lo, tgt, acc = carry
+        take = (lift(cwin.row(s)) <= tgt) | (s == s_lo)
+        fresh = vals_at(s, tgt.shape)
+        return s_lo, tgt, {k: jnp.where(take, fresh[k], acc[k])
+                           for k in acc}
 
-    acc = vals_at(lo)
-    if hi > lo + 1:
-        acc = jax.lax.fori_loop(lo + 1, hi, body, acc)
+    def march(tgt, s_lo, s_hi):
+        acc = {(fl.name, fl.di, fl.dj, fl.dk):
+               tgt.astype(cols[(fl.name, fl.di, fl.dj)].dtype) for fl in finds}
+        return jax.lax.fori_loop(s_lo, s_hi + 1, body, (s_lo, tgt, acc))[2]
+
+    if hi <= lo + 1:
+        acc = vals_at(lo, shape)
+    else:
+        trows = target if has_rows else target[..., None, :, :]
+        rows = band_rows(shape[:-3] + shape[-2:] if has_rows else shape,
+                         len(finds))
+        accs = [march(trows[..., r0:r1, :, :] if has_rows else target,
+                      s_lo, s_hi)
+                for r0, r1, s_lo, s_hi in _block_bands(
+                    cwin.whole(), trows, live, lo, hi, rows)]
+        acc = {k: (jnp.concatenate([a[k] for a in accs], axis=-3)
+                   if len(accs) > 1 else accs[0][k]) for k in accs[0]}
 
     def found(fl: FoundLevel):
         return acc[(fl.name, fl.di, fl.dj, fl.dk)]
@@ -193,17 +345,19 @@ def _march_search(e: LevelSearch, read, params, read_col, nk: int):
                        found=found)
 
 
-def _eval_block(e: Expr, read, params, read_col=None, nk=None, found=None):
+def _eval_block(e: Expr, read, params, read_col=None, nk=None, found=None,
+                live=None):
     """Evaluate expression over a block; ``read(name, off)`` yields arrays.
 
     ``read_col(name, di, dj)`` yields a field's *whole* K column over the
     horizontal window — required (and only available under whole-K blocks)
     for :class:`LevelSearch` lowering; ``found`` resolves FoundLevel
-    accesses inside a search body.
+    accesses inside a search body; ``live`` masks the window's cells whose
+    value the statement writes (None: all), which bound a search's band.
     """
     def ev(x, found=found):
         return _eval_block(x, read, params, read_col=read_col, nk=nk,
-                           found=found)
+                           found=found, live=live)
 
     if isinstance(e, Const):
         return e.value
@@ -212,7 +366,7 @@ def _eval_block(e: Expr, read, params, read_col=None, nk=None, found=None):
     if isinstance(e, FieldAccess):
         return read(e.name, e.offset)
     if isinstance(e, LevelSearch):
-        return _march_search(e, read, params, read_col, nk)
+        return _march_search(e, read, params, read_col, nk, live)
     if isinstance(e, FoundLevel):
         if found is None:
             raise TypeError("FoundLevel outside a LevelSearch body")
@@ -515,8 +669,10 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             kk = (jax.lax.broadcasted_iota(
                 jnp.int32, (rows, nj_w, ni_w), 0) + k0)
             tshape = lead + (rows, nj_w, ni_w)
+            pm = tile.mask(st.region, pid)
             val = _eval_block(st.value, make_read(rows), params,
-                              read_col=read_col if whole_k else None, nk=nk)
+                              read_col=read_col if whole_k else None, nk=nk,
+                              live=pm)
             klo, khi = st.interval.resolve(tgt_nk)
             jsl, isl = tile.window()
             tgt_ref = out_refs.get(st.target)
@@ -531,7 +687,6 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
             val = jnp.broadcast_to(val, tshape).astype(dt)
             cur = jnp.broadcast_to(cur, tshape).astype(dt)
             mask = (kk >= klo) & (kk < khi)
-            pm = tile.mask(st.region, pid)
             if pm is not None:
                 mask = mask & pm[None]
             new = jnp.where(mask, val, cur)
@@ -635,14 +790,14 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                         di, dj, dk = off
                         js, is_ = tile.window(dj, di)
                         return _kshift_read(ref_of(name), dk, rows, js, is_)
+                    pm = tile.mask(st.region, jt)
                     val = _eval_block(st.value, read_par, params,
-                                      read_col=read_col, nk=nk)
+                                      read_col=read_col, nk=nk, live=pm)
                     klo, khi = st.interval.resolve(rows)
                     tgt = ref_of(st.target)
                     cur = tgt[..., jsl, isl]
                     val = jnp.broadcast_to(val, cur.shape).astype(cur.dtype)
                     mask = (kk >= klo) & (kk < khi)
-                    pm = tile.mask(st.region, jt)
                     if pm is not None:
                         mask = mask & pm[None]
                     tgt[..., jsl, isl] = jnp.where(mask, val, cur)
@@ -678,13 +833,13 @@ def _vertical_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
                 new_carry = dict(carry)
                 for st in comp.statements:
                     sklo, skhi = st.interval.resolve(ksz.get(st.target, nk))
+                    pm = tile.mask(st.region, jt)
                     val = _eval_block(st.value, read_lvl, params,
-                                      read_col=read_col, nk=nk)
+                                      read_col=read_col, nk=nk, live=pm)
                     tgt = ref_of(st.target)
                     cur = lvl_get(tgt, k, jsl, isl)
                     val = jnp.broadcast_to(val, cur.shape).astype(cur.dtype)
                     active = (k >= sklo) & (k < skhi)
-                    pm = tile.mask(st.region, jt)
                     if pm is not None:
                         val = jnp.where(pm, val, cur)
                     newv = jnp.where(active, val, cur)

@@ -49,9 +49,11 @@ def node_flops(program: StencilProgram, node: Node) -> int:
     ei, ej = node.extend
     vol = dom.nk * (dom.nj + 2 * ej) * (dom.ni + 2 * ei)
     flops = vol * node.stencil.flops()
-    # a LevelSearch marches O(nk) source layers per output point (compare +
-    # two selects per layer in the Pallas lowering; the jnp bisection is
-    # cheaper but the bound prices the worst backend) — nk-dependent, so it
+    # a LevelSearch is priced as a march over O(nk) source layers per output
+    # point (compare + two selects per layer): the worst case of the Pallas
+    # lowering, whose band-limited march walks only the layers that can
+    # bracket a block of targets and all of them where a window's column is
+    # not monotone; the jnp bisection is cheaper — nk-dependent, so it
     # cannot live in the stencil's static per-point count
     n_search = node.stencil.count_level_searches()
     if n_search:

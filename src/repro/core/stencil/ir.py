@@ -298,9 +298,13 @@ class LevelSearch(Expr):
     interpolation within the bracketing layer.
 
     Backends lower the search to *real loops* — ``lax.fori_loop`` bisection
-    in the jnp lowering, an in-kernel marching loop in Pallas — so the IR
-    and trace stay O(1) in ``nk`` instead of the O(nk²) static-offset
-    unrolling the construct replaces.
+    in the jnp lowering; in Pallas, band-limited marching loops: the target
+    rows split into blocks, and each block walks only the source layers
+    that can bracket its targets over the kernel's window (bounds from the
+    column's per-level extremes, which this monotone contract makes exact;
+    a window that breaks it marches every layer) — so the IR stays O(1) in
+    ``nk`` instead of the O(nk²) static-offset unrolling the construct
+    replaces.
     """
 
     coord: str

@@ -336,11 +336,13 @@ def interface_interp(fm: Field[interface], pe: Field[interface],
     float drift at the column ends extrapolate linearly); ``at_found``
     reads the layer's bounding interfaces for the linear interpolation.
     The backends lower the search to *real loops* — ``lax.fori_loop``
-    bisection in jnp, an in-kernel marching loop in Pallas — so the
-    stencil's IR is a constant ~20 nodes at any nk, where the unrolled
-    variant below pays O(nk²).  The slope guard only fires for
-    zero-thickness Lagrangian layers, whose mass increment is itself zero —
-    conservation is untouched.
+    bisection in jnp; in Pallas, marching loops over blocks of reference
+    interfaces, each walking only the Lagrangian layers that can bracket
+    its block (a few layers, since the surfaces move a fraction of a layer
+    between remaps) — so the stencil's IR is a constant ~20 nodes at any
+    nk, where the unrolled variant below pays O(nk²).  The slope guard
+    only fires for zero-thickness Lagrangian layers, whose mass increment
+    is itself zero — conservation is untouched.
     """
     with computation(PARALLEL), interval(...):
         fi = index_search(

@@ -160,6 +160,123 @@ def test_search_interp_jnp_pallas_bit_equal():
     np.testing.assert_array_equal(outs["jnp"][I], outs["pallas-tpu"][I])
 
 
+def _band_columns(case, nk, dom, rng, lead=()):
+    """(pe, fm, pe_ref) interface columns for one band-limited search case:
+    ``pe`` the Lagrangian interfaces (the search's coordinate), ``pe_ref``
+    the reference interfaces it brackets."""
+    shape = lead + dom.padded_shape()
+    kprof = ((np.arange(nk) + 0.5) / nk)[:, None, None]
+    if case == "initial_offset":
+        # the dycore's initial thickness profile: up to 0.05 nk layers off
+        # the uniform reference, columns apart by a smooth factor
+        delp = rng.uniform(1.0, 1.02, lead + (1,) + shape[-2:]) \
+            * (0.8 + 0.4 * kprof)
+    elif case == "ties":
+        delp = rng.integers(1, 3, shape).astype(np.float64)
+    else:
+        delp = 1.0 + 0.01 * rng.standard_normal(shape)
+    if case == "zero_thickness":
+        delp[..., ::5, :, :] = 0.0
+        delp[..., :3, :, :] = 0.0
+    delp = np.broadcast_to(delp, shape).astype(np.float32)
+    zero = np.zeros(lead + (1,) + shape[-2:], np.float32)
+    pe = np.cumsum(np.concatenate([zero + 10.0, delp], -3), -3,
+                   dtype=np.float32)
+    q = rng.uniform(0.5, 1.5, shape).astype(np.float32)
+    fm = np.cumsum(np.concatenate([zero, q * delp], -3), -3, dtype=np.float32)
+    total = pe[..., -1:, :, :] - 10.0
+    sigma = (np.arange(nk + 1, dtype=np.float32) / nk)[:, None, None]
+    pe_ref = (10.0 + sigma * total).astype(np.float32)
+    if case == "ties":
+        pe_ref = pe.copy()          # every target equals a coordinate
+    elif case == "catch_alls":
+        # targets above the first searched interface and below the last
+        pe_ref = (5.0 + sigma * (total + 10.0)).astype(np.float32)
+    elif case == "non_monotone":
+        # a deep interface dips to the top of its column: the full march
+        # selects it for every target above it
+        col = (Ellipsis, slice(None), dom.halo + 1, dom.halo + 2)
+        pe[col][..., nk - 3] = pe[col][..., 2]
+    return pe, fm, pe_ref
+
+
+_BAND_CASES = {
+    # case: (data, nk, members, batch, J-tile rows)
+    "near_reference": ("near_reference", 24, None, "vmap", 4),
+    "initial_offset_nk80": ("initial_offset", 80, None, "vmap", 0),
+    "ties": ("ties", 24, None, "vmap", 4),
+    "zero_thickness": ("zero_thickness", 24, None, "vmap", 4),
+    "catch_alls": ("catch_alls", 24, None, "vmap", 4),
+    "non_monotone": ("non_monotone", 24, None, "vmap", 4),
+    "member_grid_m2": ("initial_offset", 24, 2, "grid", 4),
+    "chunked_members": ("near_reference", 24, 4, "vmap:2,grid", 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BAND_CASES))
+def test_band_search_matches_full_march(case, monkeypatch):
+    """The band-limited Pallas search selects what a march over every
+    source layer selects, bit for bit: the full march is the same lowering
+    with each block's band widened to the whole ``[lo, hi-1]``."""
+    from repro.core.backend import lowering_pallas as LP
+
+    data, nk, members, batch, bj = _BAND_CASES[case]
+    dom = DomainSpec(ni=6, nj=10, nk=nk, halo=2)
+    lead = (members,) if members else ()
+    pe, fm, pe_ref = _band_columns(data, nk, dom, np.random.default_rng(3),
+                                   lead)
+    if case == "non_monotone":
+        assert np.any(np.diff(pe, axis=-3) < 0)
+    ins = {"fm": jnp.asarray(fm), "pe": jnp.asarray(pe),
+           "pe_ref": jnp.asarray(pe_ref),
+           "fi": jnp.zeros(lead + dom.padded_shape(interface=True),
+                           jnp.float32)}
+    sched = Schedule(block_j=bj)
+
+    def run():
+        fn = compile_stencil(S.interface_interp, dom, backend="pallas-tpu",
+                             schedule=sched, memoize=False,
+                             n_members=members, batch=batch)
+        return np.asarray(fn(dict(ins), {})["fi"])
+
+    band = run()
+    # cmax holds the compared levels lo+1 .. hi-1
+    monkeypatch.setattr(LP, "_band", lambda cmax, cmin, tmin, tmax, lo: (
+        jnp.int32(lo), jnp.int32(lo + cmax.shape[0])))
+    full = run()
+    np.testing.assert_array_equal(band, full)
+    assert np.isfinite(band).all()
+
+
+@pytest.mark.parametrize("span", ["column", "reference"])
+def test_search_band_share(span):
+    """The counter of marched pairs: a window whose surfaces span the whole
+    column marches everything; one on the reference marches about a
+    block's rows plus two layers per block."""
+    from repro.core.backend.lowering_pallas import band_rows, \
+        search_band_share
+
+    nk, window = 80, (8, 128)     # a C128 tile's J tile, as the remap runs
+    rows = band_rows(window, 4)
+    assert rows == 4
+    rng = np.random.default_rng(0)
+    sigma = (np.arange(nk + 1, dtype=np.float32) / nk)[:, None, None]
+    scale = rng.uniform(1.0, 1.02, (2, 1) + window).astype(np.float32)
+    pe = (10.0 + 80.0 * sigma * scale).astype(np.float32)
+    if span == "column":
+        # one column's surfaces crowd at the top, another's at the bottom
+        pe[0, :, 0, 0] = 10.0 + 1e-3 * np.arange(nk + 1)
+        pe[0, :, 0, 1] = 90.0 + 1e-3 * np.arange(nk + 1)
+    share = search_band_share(pe, pe.copy(), window, lo=0, hi=nk, n_found=4)
+    if span == "column":
+        assert share > 0.5
+        one = search_band_share(pe[:1], pe[:1].copy(), window, lo=0, hi=nk,
+                                n_found=4)
+        assert one == 1.0
+    else:
+        assert share <= (rows + 2) / nk
+
+
 def test_search_matches_unrolled_path():
     """The construct replaces the unrolled where-chain bit for bit."""
     cfg = FV3Config(npx=4, nk=6, halo=6, n_tracers=0)

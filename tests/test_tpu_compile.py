@@ -130,8 +130,11 @@ def test_vertical_solver_tuned_schedule_compiles(one_chip, native, stencil,
 
 
 def test_remap_index_search_compiles(one_chip, native):
-    """interface_interp: the level search marches whole K-interface
-    columns, reading each level from the ref at a traced index."""
+    """interface_interp: the band-limited level search — the coordinate
+    column's per-level extremes and each row block's target extremes
+    reduced to scalar loop bounds inside the kernel, then one march per
+    block over its band, reading each level from the ref at a traced
+    index."""
     sched = default_schedule(S.interface_interp, DOM)
     _check(S.interface_interp, sched, one_chip)
 
