@@ -20,8 +20,8 @@ the raw IR with no shared code:
     a pure shift).
 
  3. **K-blocked marching boundary races**: a node whose schedule requests
-    the K-blocked marching lowering (sequential ``block_k`` < nk dividing
-    nk) must satisfy the single-level-carry contract — one marching
+    the K-blocked marching lowering (sequential ``block_k`` < nk; the last
+    block is ragged where it does not divide nk) must satisfy the single-level-carry contract — one marching
     direction, K reads only at the current or marching-previous level, the
     previous-level (carry) reads horizontal-offset-free and never of a
     field a later computation writes, no interface fields, no level search.
@@ -43,7 +43,7 @@ def _node_schedule_requests_kblock(node, nk: int) -> bool:
     if sched is None or sched.k_as_grid:
         return False
     bk = sched.block_k
-    return bool(bk) and bk < nk and nk % bk == 0
+    return bool(bk) and bk < nk
 
 
 def _check_marching(st: Stencil, *, program, node) -> list[Violation]:

@@ -24,7 +24,7 @@ import numpy as np
 from .hardware import Hardware, resolve_hardware
 from .stencil.domain import DomainSpec
 from .stencil.ir import Stencil
-from .stencil.schedule import (Schedule, kblocked_applies,
+from .stencil.schedule import (Schedule, k_blocks, kblocked_applies,
                                solver_carried_fields, vmem_footprint)
 
 
@@ -36,6 +36,8 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
     bytes/bw plus structural penalties:
       * K-slab grids re-stage the halo of every block boundary (negligible
         unless blocks are tiny) — modeled as per-block fixed overhead;
+      * a ragged last K block (``block_k`` not dividing nk) walks padded
+        rows past the column: priced as that many more levels of data;
       * vertical solvers with 'vmem' carries re-read each written field once
         per level (the §VI-A.2(3) transform removes exactly this);
       * 'split' region kernels add a launch overhead per region but shrink
@@ -72,6 +74,11 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
     t = data / hw.hbm_bw
 
     launch_overhead = 1e-6  # per pallas_call / grid step pipeline fill
+
+    def padded(bk: int) -> float:
+        # the rows past nk a ragged last block of ``bk`` levels walks
+        return data * (bk - k_blocks(nk, bk)[1]) / nk / hw.hbm_bw
+
     if stencil.is_vertical_solver():
         if vmem_footprint(stencil, sched, dom, dtype_bytes,
                           member_chunk=C, hw=hw) > hw.vmem_bytes:
@@ -87,12 +94,13 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
             # carry planes staged through scratch at every block boundary
             # (total carry traffic is per member — chunking doesn't move
             # fewer bytes, it just stages C members per grid step)
-            n_blocks = max(1, nk // bk)
+            n_blocks = k_blocks(nk, bk)[0]
             plane = (nj + 2 * dom.extend[1]) * (ni + 2 * dom.extend[0])
             carry_bytes = (len(solver_carried_fields(stencil))
                            * plane * dtype_bytes)
             t += launch_overhead * (1 + 0.05 * (n_blocks * m_steps - 1))
             t += 2 * M * (n_blocks - 1) * carry_bytes / hw.hbm_bw
+            t += padded(bk)
         else:
             if sched.carry_storage == "vmem":
                 # re-read previously written levels from VMEM→VREG each
@@ -101,8 +109,9 @@ def model_cost(stencil: Stencil, sched: Schedule, dom: DomainSpec,
                 t += 0.25 * extra / hw.hbm_bw
             t += launch_overhead * (1 + 0.05 * (m_steps - 1))
     else:
-        bk = sched.block_k or nk
-        n_blocks = max(1, nk // bk)
+        bk = min(sched.block_k or nk, nk)
+        n_blocks = k_blocks(nk, bk)[0]
+        t += padded(bk)
         if hw.kind == "gpu":
             # thread-block grid: blocks along all three tile dims
             bi = sched.block_i or ni

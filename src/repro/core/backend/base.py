@@ -103,6 +103,13 @@ class Backend(abc.ABC):
         return heuristic_schedule(stencil, dom_shape,
                                   hw=self.resolve_hw(hardware))
 
+    def k_block(self, stencil: Stencil, dom: DomainSpec,
+                schedule: Schedule | None = None,
+                hardware: Hardware | str | None = None) -> int:
+        """Levels per block in which this backend's kernel of ``stencil``
+        walks K (0: whole columns, as a backend without K blocks does)."""
+        return 0
+
     def __repr__(self):
         return f"<backend {self.name!r} (default hw {self.default_hardware})>"
 

@@ -291,8 +291,10 @@ def compile_program(program: "StencilProgram",
     ``None`` at level 0), ``program`` (the graph actually lowered),
     ``input_fields`` and ``transient_inputs`` (fields auto-allocated when
     the caller omits them — empty of transients once fusion has localized
-    them), plus ``n_members`` / ``batch`` / ``batch_spec`` /
-    ``member_chunk`` / ``n_chunks`` describing the ensemble lowering.
+    them), ``k_blocks`` (``(node label, block_k, nk)`` of each kernel that
+    walks K in slabs or blocks), plus ``n_members`` / ``batch`` /
+    ``batch_spec`` / ``member_chunk`` / ``n_chunks`` describing the ensemble
+    lowering.
     """
     be = get_backend(backend)
     hw = be.resolve_hw(hardware)
@@ -342,6 +344,7 @@ def compile_program(program: "StencilProgram",
     elif chunk_grid:
         stencil_members = Mp
     runners = []
+    k_blocks = []
     for s in program.states:
         for n in s.nodes:
             dom = program.node_dom(n)
@@ -350,6 +353,9 @@ def compile_program(program: "StencilProgram",
                                 hardware=hw, n_members=stencil_members,
                                 batch=stencil_batch)
             runners.append((n, r))
+            bk = be.k_block(n.stencil, dom, sched, hw)
+            if bk:
+                k_blocks.append((n.label, bk, dom.nk))
 
     fields_decl = program.fields
     dom = program.dom
@@ -420,6 +426,7 @@ def compile_program(program: "StencilProgram",
             return jitted(fields, params)
 
     fn.n_kernels = len(runners)
+    fn.k_blocks = tuple(k_blocks)
     fn.n_members = n_members
     fn.batch = spec.token if n_members else None
     fn.batch_spec = eff if n_members else None

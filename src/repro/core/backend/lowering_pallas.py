@@ -6,6 +6,8 @@ Schedules map onto Pallas as follows (paper §V-A ↔ TPU):
    ``(block_k, NJ+2h, NI+2h)`` VMEM block per field.  Horizontal offsets are
    in-block static slices (VREG shifts); K is the parallel ("map") dimension —
    the paper's ``[Interval, Operation, K, J, I]`` order with I on lanes.
+   Where ``block_k`` does not divide nk the last slab is a partial (edge)
+   block: its rows past nk are padding, masked by level and never stored.
  * vertical (FORWARD/BACKWARD) solvers: one full-column block; an in-kernel
    ``fori_loop`` walks K.  With ``carry_storage='vreg'`` loop-carried values
    live in registers across iterations (paper §VI-A.2 transform 3); with
@@ -72,8 +74,9 @@ from ..stencil.ir import (
     Where,
     expr_contains_level_search,
 )
-from ..stencil.schedule import (Schedule, default_schedule, j_tile,
-                                kblocked_applies, solver_carried_fields)
+from ..stencil.schedule import (Schedule, default_schedule, j_tile, k_block,
+                                k_blocks, kblocked_applies,
+                                solver_carried_fields)
 
 _UNARY = {
     "neg": lambda x: -x,
@@ -582,14 +585,9 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
     nk = dom.nk
     ksz = {f: stencil.k_extent_of(f, nk)
            for f in list(fields) + list(temps)}
-    bk = sched.block_k if (sched.block_k and sched.k_as_grid) else nk
-    if any(st.value.accesses() and any(a.offset[2] != 0 for a in st.value.accesses())
-           for st in statements):
-        bk = nk  # K offsets require whole-column blocks
-    if stencil.has_interface_fields():
-        bk = nk  # interface and center fields never co-tile in K
-    if stencil.has_level_search():
-        bk = nk  # the search marches whole coordinate columns
+    # whole columns for K offsets, interface fields (never co-tiled with
+    # centers in K) and level searches (whole coordinate columns)
+    bk = k_block(stencil, sched, nk) or nk
     whole_k = bk == nk
     # whole-column stencils with no horizontal reads may tile J instead
     tile = _Tile(dom, j_tile(stencil, sched) if whole_k else 0)
@@ -702,7 +700,9 @@ def _horizontal_kernel(stencil: Stencil, dom: DomainSpec, sched: Schedule,
         def block(f_rows):
             return pl.BlockSpec((f_rows, tile.bj, nip), lambda j: (0, j, 0))
     else:
-        grid = (nk // bk,)
+        # a ragged last slab is an edge block: Pallas pads its reads and
+        # drops its stores past nk
+        grid = (pl.cdiv(nk, bk),)
 
         def block(f_rows):
             return pl.BlockSpec((f_rows, tile.rows(), nip),
@@ -878,10 +878,14 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
     """K-blocked marching schedule for single-direction vertical solvers.
 
     The TPU grid executes *sequentially*, so the K dimension becomes a grid
-    of ``nk // block_k`` slabs walked in marching order (top-down FORWARD,
-    bottom-up BACKWARD via a reversed index map); each invocation holds one
-    ``(block_k, J, I)`` VMEM block per field and marches its levels with an
-    in-kernel ``fori_loop``.  Loop-carried values — the marching-previous
+    of ``cdiv(nk, block_k)`` slabs walked in marching order (top-down
+    FORWARD, bottom-up BACKWARD via a reversed index map); each invocation
+    holds one ``(block_k, J, I)`` VMEM block per field and marches its levels
+    with an in-kernel ``fori_loop``.  Where ``block_k`` does not divide nk
+    the bottom block is ragged: an edge block whose rows past nk are
+    padding, and its loop marches only its real levels — the last block
+    marched going down, the first going up, whose carry then starts at
+    level nk - 1.  Loop-carried values — the marching-previous
     level of every field read at that offset, written *or* input — live in
     registers within the block and cross block boundaries through VMEM
     scratch planes that persist across grid steps.  Legality is exactly
@@ -892,7 +896,7 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
     temps = stencil.temporaries()
     nk = dom.nk
     bk = sched.block_k
-    n_blocks = nk // bk
+    n_blocks, last = k_blocks(nk, bk)
     dirs = {c.direction for c in stencil.computations
             if c.direction is not Direction.PARALLEL}
     forward = Direction.FORWARD in dirs
@@ -923,6 +927,8 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
         # below resets at every member/chunk boundary — no carry leaks.
         blk = g if forward else (n_blocks - 1 - g)
         k0 = blk * bk
+        # levels this block marches: all of them, but in a ragged bottom block
+        rows = bk if last == bk else jnp.where(blk == n_blocks - 1, last, bk)
 
         def ref_of(name):
             if name in out_refs:
@@ -955,7 +961,7 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
                   for n in carried}
 
         def body(step, carry):
-            local = step if forward else bk - 1 - step
+            local = step if forward else rows - 1 - step
             k = k0 + local  # absolute level, for interval masks
 
             def read_lvl(name, off):
@@ -991,7 +997,7 @@ def _vertical_kernel_kblocked(stencil: Stencil, dom: DomainSpec,
                     new_carry[n] = lvl_get(ref_of(n), local, jsl, isl)
             return new_carry
 
-        final = jax.lax.fori_loop(0, bk, body, carry0)
+        final = jax.lax.fori_loop(0, rows, body, carry0)
         for n in carried:
             carry_refs[n][...] = final[n]
         return

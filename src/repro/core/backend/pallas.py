@@ -17,7 +17,7 @@ import jax
 from ..hardware import Hardware
 from ..stencil.domain import DomainSpec
 from ..stencil.ir import Stencil
-from ..stencil.schedule import Schedule
+from ..stencil.schedule import Schedule, k_block
 from .base import Backend, Runner, register_backend
 from .batching import BatchSpec, pad_wrapped, parse_batch, scan_chunked
 from .lowering_pallas import compile_pallas
@@ -76,6 +76,13 @@ class PallasTPUBackend(Backend):
             # its own grid dimension)
             return jax.vmap(lower(), in_axes=(0, None))
         return lower(members=n_members)
+
+    def k_block(self, stencil: Stencil, dom: DomainSpec,
+                schedule: Schedule | None = None,
+                hardware: Hardware | str | None = None) -> int:
+        if schedule is None:
+            schedule = self.default_schedule(stencil, dom, hardware)
+        return k_block(stencil, schedule, dom.nk, scratch=self.scratch_temps)
 
 
 class PallasGPUBackend(PallasTPUBackend):

@@ -38,7 +38,8 @@ class Schedule:
     # order), each invocation marches ``block_k`` levels in VMEM and the
     # loop carry crosses block boundaries through persistent scratch —
     # production-depth columns (nk ~ 80) fit VMEM without giving up the
-    # sequential solve.
+    # sequential solve.  ``block_k`` need not divide nk: the last block of
+    # a K slab or march is ragged (:func:`k_blocks`).
     block_i: int = 0
     block_j: int = 0
     block_k: int = 8
@@ -139,9 +140,36 @@ def kblocked_applies(stencil: Stencil, sched: Schedule, nk: int, *,
     the lowering (``compile_pallas``, which passes its backend's scratch
     capability), the footprint model (:func:`vmem_footprint`) and the cost
     model (``model_cost``), so the model never prices a blocked kernel the
-    lowering would decline in favor of whole-column (or vice versa)."""
+    lowering would decline in favor of whole-column (or vice versa).  The
+    block need not divide nk: the last one marches the levels left."""
     return (scratch and bool(sched.block_k) and sched.block_k < nk
-            and nk % sched.block_k == 0 and solver_k_blockable(stencil))
+            and solver_k_blockable(stencil))
+
+
+def k_block(stencil: Stencil, sched: Schedule, nk: int, *,
+            scratch: bool = True) -> int:
+    """Levels per block in which the Pallas lowering walks K under
+    ``sched`` (0: whole columns) — K slabs of a horizontal stencil on the
+    grid, or the blocks a solver marches (:func:`kblocked_applies`).
+    Shared by the lowering, :func:`vmem_footprint` and ``step.k_slabs``."""
+    if stencil.is_vertical_solver():
+        return (sched.block_k
+                if kblocked_applies(stencil, sched, nk, scratch=scratch)
+                else 0)
+    if (not sched.k_as_grid or not sched.block_k or sched.block_k >= nk
+            or stencil.has_k_offsets() or stencil.has_interface_fields()
+            or stencil.has_level_search()):
+        return 0
+    return sched.block_k
+
+
+def k_blocks(nk: int, bk: int) -> tuple[int, int]:
+    """``(n_blocks, last)``: blocks of ``bk`` levels covering ``nk``, and
+    the levels of the last one, which is ragged where ``bk`` does not
+    divide ``nk`` (its rows past nk are padding: never stored, and never
+    marched)."""
+    n = -(-nk // bk)
+    return n, nk - (n - 1) * bk
 
 
 def j_tileable(stencil: Stencil) -> bool:
@@ -199,13 +227,8 @@ def vmem_footprint(stencil: Stencil, sched: Schedule, dom_shape,
     nk, nj, ni, halo = _dims(dom_shape)
     mult = max(1, member_chunk)
     vertical = stencil.is_vertical_solver()
-    if vertical:
-        whole_k = not kblocked_applies(stencil, sched, nk)
-        bk = nk if whole_k else sched.block_k
-    else:
-        whole_k = (not sched.k_as_grid or stencil.has_interface_fields()
-                   or stencil.has_level_search() or stencil.has_k_offsets())
-        bk = nk if whole_k else (sched.block_k or nk)
+    bk = k_block(stencil, sched, nk) or nk
+    whole_k = bk == nk
 
     def rows(name):
         return bk + 1 if (whole_k and stencil.is_interface(name)) else bk
@@ -228,8 +251,20 @@ def vmem_footprint(stencil: Stencil, sched: Schedule, dom_shape,
 
 
 def _k_slabs(nk: int) -> list[int]:
-    """K-slab heights a horizontal kernel's grid can walk: divisors of nk."""
-    return [b for b in (16, 8, 4, 1) if b < nk and nk % b == 0]
+    """K-slab heights a horizontal kernel's grid can walk; the last slab is
+    ragged where one does not divide nk."""
+    return [b for b in (16, 8, 4, 1) if b < nk]
+
+
+def _k_heights(heights, nk: int) -> list[int]:
+    """K block heights below nk, cheapest first: the fewest blocks plus
+    padded rows of a ragged last block (a grid step and a padded plane
+    cost about alike), the tallest first among equals.  Where every height
+    divides nk this is tallest first."""
+    def cost(b):
+        n, last = k_blocks(nk, b)
+        return n + b - last
+    return sorted((b for b in heights if b < nk), key=lambda b: (cost(b), -b))
 
 
 def _feasible_tpu(stencil: Stencil, dom_shape, dtype_bytes: int,
@@ -250,8 +285,7 @@ def _feasible_tpu(stencil: Stencil, dom_shape, dtype_bytes: int,
         # boundaries in scratch — production-depth columns fit VMEM
         k_opts = [0]
         if solver_k_blockable(stencil):
-            k_opts += [b for b in (4, 8, 16, 32)
-                       if b < nk and nk % b == 0]
+            k_opts += [b for b in (4, 8, 16, 32) if b < nk]
     else:
         k_opts = ([0] if (stencil.has_interface_fields()
                           or stencil.has_level_search())
@@ -283,25 +317,28 @@ def _feasible_tpu(stencil: Stencil, dom_shape, dtype_bytes: int,
 
 def _fitting_tpu(stencil: Stencil, base: Schedule, dom_shape,
                  dtype_bytes: int, hw: Hardware, budget: int) -> Schedule:
-    """``base`` if its blocks fit ``budget``; else the largest K block or J
-    tile the stencil admits that does (the smallest one if none fits)."""
+    """``base`` if its blocks fit ``budget``; else the first K block (in the
+    order of :func:`_k_heights`) or the largest J tile the stencil admits
+    that does (the one with the smallest blocks if none fits)."""
     nk = _dims(dom_shape)[0]
     cands = [base]
     if stencil.is_vertical_solver() and solver_k_blockable(stencil):
         cands += [dataclasses.replace(base, block_k=b, carry_storage="vreg")
-                  for b in (32, 16, 8, 4) if b < nk and nk % b == 0]
+                  for b in _k_heights((32, 16, 8, 4), nk)]
     if j_tileable(stencil):
         cands += [dataclasses.replace(base, block_j=b * hw.sublane)
                   for b in (16, 4, 1)]
     if not stencil.is_vertical_solver() and not (
             stencil.has_k_offsets() or stencil.has_interface_fields()
             or stencil.has_level_search()):
-        cands += [dataclasses.replace(base, block_k=b) for b in _k_slabs(nk)]
-    for s in cands:
-        if vmem_footprint(stencil, s, dom_shape, dtype_bytes,
-                          hw=hw) <= budget:
+        cands += [dataclasses.replace(base, block_k=b)
+                  for b in _k_heights(_k_slabs(nk), nk)]
+    fp = [vmem_footprint(stencil, s, dom_shape, dtype_bytes, hw=hw)
+          for s in cands]
+    for s, b in zip(cands, fp):
+        if b <= budget:
             return s
-    return cands[-1]
+    return cands[fp.index(min(fp))]
 
 
 def _feasible_gpu(stencil: Stencil, dom_shape, dtype_bytes: int,

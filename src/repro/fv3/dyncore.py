@@ -40,6 +40,7 @@ from repro.core import StencilProgram, compile_program
 from repro.core.backend import jit_program, register_cache_clear
 from repro.core.backend.batching import BatchSpec, parse_batch, scan_chunked
 from repro.core.stencil import DomainSpec
+from repro.core.stencil.schedule import k_blocks
 from . import stencils as S
 from .halo import exchange_reference, make_halo_exchanger
 from .overlap import make_overlapped_runner
@@ -366,6 +367,21 @@ def _build_seconds(t0: float, progs, runners, seconds: dict) -> dict:
             "rewrite": rewrite}
 
 
+def _k_slabs(progs, runners) -> dict:
+    """``step.k_slabs``: per program, the number of kernels that walk K in
+    slabs (horizontal stencils) or marched blocks (vertical solvers), and
+    per kernel its ``block_k``, ``n_blocks`` and the levels of its last
+    block (fewer than ``block_k`` where the block does not divide nk)."""
+    out = {}
+    for p, r in zip(progs, runners):
+        blocks = {}
+        for label, bk, nk in r.k_blocks:
+            n, last = k_blocks(nk, bk)
+            blocks[label] = {"block_k": bk, "n_blocks": n, "last": last}
+        out[p.name] = {"kernels": len(blocks), "blocks": blocks}
+    return out
+
+
 def _make_programs(cfg: FV3Config, dom: DomainSpec, backend: str,
                    opt_level: int, hardware=None,
                    n_members: int | None = None, batch: str = "vmap",
@@ -643,8 +659,9 @@ def make_step_sequential(cfg: FV3Config, *, backend: str = "jnp",
     The returned callable exposes ``opt_report`` (per-program pass-pipeline
     reports covering acoustic + tracer + remap), ``n_kernels`` and
     ``counters`` (trace/dispatch instrumentation used by the
-    dispatch-count tests and benchmarks) and ``build_seconds`` (host
-    seconds of the factory, per program and for the rewrite ladder).
+    dispatch-count tests and benchmarks), ``build_seconds`` (host
+    seconds of the factory, per program and for the rewrite ladder) and
+    ``k_slabs`` (the kernels that walk K in blocks, see :func:`_k_slabs`).
     """
     t0 = time.perf_counter()
     dom = cfg.seq_dom()
@@ -661,6 +678,7 @@ def make_step_sequential(cfg: FV3Config, *, backend: str = "jnp",
                           _reference_halo_fn(cfg), metrics, params, counters,
                           backend=backend, unroll=unroll, donate=donate)
     step.build_seconds = _build_seconds(t0, progs, runners, seconds)
+    step.k_slabs = _k_slabs(progs, runners)
     return step
 
 
@@ -735,6 +753,7 @@ def make_step_ensemble(cfg: FV3Config, n_members: int, *,
     step.n_chunks = (-(-n_members // member_chunks[1])
                      if member_chunks else runners[0].n_chunks)
     step.build_seconds = _build_seconds(t0, progs, runners, seconds)
+    step.k_slabs = _k_slabs(progs, runners)
     return step
 
 
@@ -919,4 +938,5 @@ def make_step_distributed(cfg: FV3Config, mesh, *, backend: str = "jnp",
     step.overlapped = ov is not None
     step.delpc_exchange_skipped = skip_delpc
     step.build_seconds = _build_seconds(t0, progs, runners, seconds)
+    step.k_slabs = _k_slabs(progs, runners)
     return step

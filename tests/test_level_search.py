@@ -350,6 +350,14 @@ def _bwd_subst(rhs: Field, cc: Field, pp: Field):
 
 
 @gtstencil
+def _bwd_open_sum(d: Field, x: Field):
+    # every level reads the one below it, the bottom level too: the Pallas
+    # marches carry a zero plane into the first level they march
+    with computation(BACKWARD), interval(...):
+        x = d + x[0, 0, 1]
+
+
+@gtstencil
 def _cross_comp_prev_read(a: Field, b: Field):
     # comp1 reads comp2's target at the marching-previous level: reference
     # semantics give comp1 b's PRE-sweep values, which a per-level
@@ -432,9 +440,15 @@ def test_kblocked_schedules_enumerated_and_fit_vmem():
     (_fwd_cumsum, ("delp", "q", "fm")),
     (_bwd_subst, ("rhs", "cc", "pp")),
 ])
-@pytest.mark.parametrize("bk", [4, 8])
-def test_kblocked_kernel_matches_whole_column(stencil, fields, bk):
-    dom = DomainSpec(ni=5, nj=4, nk=16, halo=2)
+@pytest.mark.parametrize("bk,nk", [
+    pytest.param(4, 16, id="4"),
+    pytest.param(8, 16, id="8"),
+    # ragged: the last block down (first up) holds 1 or 5 levels
+    pytest.param(4, 13, id="4-nk13"),
+    pytest.param(8, 13, id="8-nk13"),
+])
+def test_kblocked_kernel_matches_whole_column(stencil, fields, bk, nk):
+    dom = DomainSpec(ni=5, nj=4, nk=nk, halo=2)
     rng = np.random.default_rng(5)
     ins = {f: jnp.asarray(rng.uniform(0.2, 1.2, dom.padded_shape()),
                           jnp.float32) for f in fields}
@@ -442,9 +456,53 @@ def test_kblocked_kernel_matches_whole_column(stencil, fields, bk):
     sched = Schedule(block_i=0, block_j=0, block_k=bk, k_as_grid=False)
     got = compile_stencil(stencil, dom, backend="pallas-tpu",
                           schedule=sched)(dict(ins), {})
+    whole = compile_stencil(stencil, dom, backend="pallas-tpu",
+                            schedule=Schedule(block_k=0, k_as_grid=False))(
+        dict(ins), {})
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(got[k]),
                                       err_msg=k)
+        np.testing.assert_array_equal(np.asarray(whole[k]),
+                                      np.asarray(got[k]), err_msg=k)
+
+
+@pytest.mark.parametrize("bk", [4, 8])
+def test_kblocked_ragged_march_starts_at_the_bottom_level(bk):
+    """Going up, the ragged block is marched first: its march starts at
+    level nk - 1 with the zero carry, never at a padded row past nk."""
+    dom = DomainSpec(ni=5, nj=4, nk=13, halo=2)
+    rng = np.random.default_rng(3)
+    ins = {f: jnp.asarray(rng.uniform(0.2, 1.2, dom.padded_shape()),
+                          jnp.float32) for f in ("d", "x")}
+    got = compile_stencil(_bwd_open_sum, dom, backend="pallas-tpu",
+                          schedule=Schedule(block_k=bk, k_as_grid=False))(
+        dict(ins), {})
+    whole = compile_stencil(_bwd_open_sum, dom, backend="pallas-tpu",
+                            schedule=Schedule(block_k=0, k_as_grid=False))(
+        dict(ins), {})
+    np.testing.assert_array_equal(np.asarray(whole["x"]), np.asarray(got["x"]))
+
+
+@pytest.mark.parametrize("bk", [4, 8])
+def test_kblocked_ragged_precompute_pe_matches_oracle(bk):
+    """d_sw's FORWARD solver at a depth no K block divides: the ragged
+    bottom block marches only its real levels."""
+    dom = DomainSpec(ni=6, nj=5, nk=13, halo=2)
+    rng = np.random.default_rng(11)
+    ins = {f: jnp.asarray(rng.uniform(0.3, 1.3, dom.padded_shape()),
+                          jnp.float32) for f in ("delp", "pe")}
+    params = {"ptop": 10.0}
+    ref = compile_stencil(S.precompute_pe, dom, backend="jnp")(
+        dict(ins), params)
+    sched = Schedule(block_k=bk, k_as_grid=False)
+    got = compile_stencil(S.precompute_pe, dom, backend="pallas-tpu",
+                          schedule=sched)(dict(ins), params)
+    whole = compile_stencil(S.precompute_pe, dom, backend="pallas-tpu",
+                            schedule=Schedule(block_k=0, k_as_grid=False))(
+        dict(ins), params)
+    np.testing.assert_array_equal(np.asarray(ref["pe"]), np.asarray(got["pe"]))
+    np.testing.assert_array_equal(np.asarray(whole["pe"]),
+                                  np.asarray(got["pe"]))
 
 
 def test_kblocked_fused_solver_legality_and_correctness():

@@ -1,5 +1,6 @@
 """Stencil DSL unit tests: parsing, oracle semantics, Pallas equivalence."""
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -145,17 +146,29 @@ def test_pallas_matches_jnp(rng, stencil, fields, params):
                                    rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("sched", [
-    Schedule(block_k=4),
-    Schedule(block_k=0),
-    Schedule(region_strategy="split"),
+@pytest.mark.parametrize("sched,nk", [
+    pytest.param(Schedule(block_k=4), 8, id="sched0"),
+    pytest.param(Schedule(block_k=0), 8, id="sched1"),
+    pytest.param(Schedule(region_strategy="split"), 8, id="sched2"),
+    # ragged K slabs: the last slab holds 1 or 5 of 13 levels
+    pytest.param(Schedule(block_k=4), 13, id="bk4-nk13"),
+    pytest.param(Schedule(block_k=8), 13, id="bk8-nk13"),
+    pytest.param(Schedule(block_k=8, region_strategy="split"), 13,
+                 id="bk8-split-nk13"),
 ])
-def test_pallas_schedules_equivalent(rng, sched):
-    fs = {f: randf(rng) for f in ("q", "u", "flux")}
-    o1 = compile_jnp(flux_region, DOM)(fs)
-    o2 = compile_pallas(flux_region, DOM, schedule=sched)(fs)
+def test_pallas_schedules_equivalent(rng, sched, nk):
+    dom = DomainSpec(ni=DOM.ni, nj=DOM.nj, nk=nk, halo=DOM.halo)
+    fs = {f: jnp.asarray(rng.uniform(0.5, 1.5, dom.padded_shape()),
+                         jnp.float32) for f in ("q", "u", "flux")}
+    o1 = compile_jnp(flux_region, dom)(fs)
+    o2 = compile_pallas(flux_region, dom, schedule=sched)(fs)
     np.testing.assert_allclose(np.asarray(o1["flux"]),
                                np.asarray(o2["flux"]), rtol=1e-5)
+    # K slabs compute what whole columns do, bit for bit
+    o3 = compile_pallas(flux_region, dom, schedule=dataclasses.replace(
+        sched, block_k=0))(fs)
+    np.testing.assert_array_equal(np.asarray(o3["flux"]),
+                                  np.asarray(o2["flux"]))
 
 
 def test_vertical_carry_storage_equivalent(rng):

@@ -4,7 +4,8 @@ Interpret mode hides what the TPU's kernel compiler (Mosaic) refuses: loads
 from memory it cannot read directly, dynamic slices of loaded values, and
 blocks larger than the scoped VMEM a kernel may use.  These tests compile
 kernels at the production width — a C128 tile padded by the halo to
-140 × 140 points, 80 levels — for a described ``v5e`` topology, the way
+140 × 140 points, 80 levels, and GFS v16's 127, which no K block divides —
+for a described ``v5e`` topology, the way
 the dycore does: through ``jit_program``, with the descriptor's kernel
 options (XLA's VMEM memory-space assignment off, so every kernel holds
 its own blocks) and its scoped-VMEM limit.  ``vmem_footprint`` counts
@@ -35,6 +36,13 @@ from repro.fv3.dyncore import FV3Config, build_remap_program
 
 #: one C128 tile at production depth: 128 + 2 * 6 = 140 points per side
 DOM = DomainSpec(ni=128, nj=128, nk=80, halo=6)
+#: production depths: the paper's 80 levels, and GFS v16's 127, where the
+#: last K slab or marched block is ragged
+DEPTHS = [80, 127]
+
+
+def _dom(nk: int) -> DomainSpec:
+    return dataclasses.replace(DOM, nk=nk)
 
 
 @pytest.fixture(scope="module")
@@ -61,9 +69,9 @@ def native(monkeypatch):
     monkeypatch.setattr(compile_mod, "detect_hardware", lambda: TPU_V5E)
 
 
-def _specs(stencil_or_program, sharding, lead=()):
+def _specs(stencil_or_program, sharding, lead=(), dom=DOM):
     fields = {f: jax.ShapeDtypeStruct(
-        lead + DOM.padded_shape(stencil_or_program.is_interface(f)),
+        lead + dom.padded_shape(stencil_or_program.is_interface(f)),
         jnp.float32, sharding=sharding)
         for f in stencil_or_program.fields}
     params = {p: jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
@@ -71,14 +79,14 @@ def _specs(stencil_or_program, sharding, lead=()):
     return fields, params
 
 
-def _compile(stencil, sched, sharding, n_members=None) -> str:
+def _compile(stencil, sched, sharding, n_members=None, dom=DOM) -> str:
     """Compiled HLO text of ``stencil`` under ``sched`` for one chip."""
-    run = compile_stencil(stencil, DOM, backend="pallas-tpu", schedule=sched,
+    run = compile_stencil(stencil, dom, backend="pallas-tpu", schedule=sched,
                           hardware=TPU_V5E, memoize=False,
                           n_members=n_members, batch="grid")
     lead = (n_members,) if n_members else ()
     return jit_program(run, "pallas-tpu").lower(
-        *_specs(stencil, sharding, lead)).compile().as_text()
+        *_specs(stencil, sharding, lead, dom)).compile().as_text()
 
 
 def _kernel_vmem(text: str) -> list[int]:
@@ -90,43 +98,53 @@ def _kernel_vmem(text: str) -> list[int]:
                                 r'"size":"(\d+)"', line)] if m]
 
 
-def _check(stencil, sched, sharding, n_members=None) -> str:
+def _check(stencil, sched, sharding, n_members=None, dom=DOM) -> str:
     """Compile as the dycore does, then hold the model to the compiler:
     the modelled blocks fit the tuner's budget, and what the compiler
     reports beyond them (its scratch for a statement's values) fits the
     headroom between that budget and the kernel's scoped-VMEM limit."""
-    text = _compile(stencil, sched, sharding, n_members)
+    text = _compile(stencil, sched, sharding, n_members, dom)
     # the kernel options took: XLA placed no array in VMEM (memory space 1)
     assert "S(1)" not in text
     used = _kernel_vmem(text)
     assert used, "no Mosaic kernel in the compiled text"
-    fp = vmem_footprint(stencil, sched, DOM, hw=TPU_V5E)
+    fp = vmem_footprint(stencil, sched, dom, hw=TPU_V5E)
     assert fp <= TPU_V5E.vmem_bytes
     headroom = TPU_V5E.vmem_limit_bytes - TPU_V5E.vmem_bytes
     assert all(u - fp <= headroom for u in used), (used, fp)
     return text
 
 
-def test_horizontal_ppm_kernel_compiles(one_chip, native):
-    """fx_ppm: halo reads in I, scalar-free, K slabs on the grid."""
-    sched = default_schedule(S.fx_ppm, DOM)
-    assert sched.block_k and sched.block_k < DOM.nk
-    _check(S.fx_ppm, sched, one_chip)
+@pytest.mark.parametrize("nk", DEPTHS)
+def test_horizontal_ppm_kernel_compiles(one_chip, native, nk):
+    """fx_ppm: halo reads in I, scalar-free, K slabs on the grid (at 127
+    levels the last slab is a partial edge block)."""
+    dom = _dom(nk)
+    sched = default_schedule(S.fx_ppm, dom)
+    assert sched.block_k and sched.block_k < nk
+    assert (nk % sched.block_k != 0) == (nk == 127)
+    _check(S.fx_ppm, sched, one_chip, dom=dom)
 
 
+@pytest.mark.parametrize("nk", DEPTHS)
 @pytest.mark.parametrize("stencil,tiling", [
     (S.tridiag_solve, "block_j"),   # two-direction solver: J tiles
     (S.precompute_pe, "block_k"),   # one-direction march: K blocks
 ])
 def test_vertical_solver_tuned_schedule_compiles(one_chip, native, stencil,
-                                                 tiling):
-    """Whole 80-level columns of a 140 x 140 tile exceed VMEM; the tuner
-    picks a J-tiled or K-blocked schedule whose blocks fit, and the kernel
-    compiles under it (SMEM scalars, the K-blocked carry scratch)."""
-    sched = tune_stencil(stencil, DOM, backend="pallas-tpu",
+                                                 tiling, nk):
+    """Whole 80- or 127-level columns of a 140 x 140 tile exceed VMEM; the
+    tuner picks a J-tiled or K-blocked schedule whose blocks fit, and the
+    kernel compiles under it (SMEM scalars, the K-blocked carry scratch;
+    at 127 levels a ragged bottom block whose march has a traced trip
+    count)."""
+    dom = _dom(nk)
+    sched = tune_stencil(stencil, dom, backend="pallas-tpu",
                          hw=TPU_V5E)[0].schedule
     assert getattr(sched, tiling) != 0, sched
-    _check(stencil, sched, one_chip)
+    if tiling == "block_k":
+        assert (nk % sched.block_k != 0) == (nk == 127)
+    _check(stencil, sched, one_chip, dom=dom)
 
 
 def test_remap_index_search_compiles(one_chip, native):
